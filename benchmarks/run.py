@@ -28,6 +28,7 @@ jax.config.update("jax_enable_x64", True)  # exact f64 conv (paper uses
 # ippsConv_64f); benchmarks run in their own process, tests are unaffected.
 
 from benchmarks.common import emit, write_bench_json  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main() -> None:
@@ -37,6 +38,7 @@ def main() -> None:
                     help="CI mode: validation subsets at small sizes")
     ap.add_argument("--only", default="")
     args = ap.parse_args()
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     ok = True

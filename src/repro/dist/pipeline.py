@@ -13,7 +13,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 def make_layer_stage(layer_fn: Callable) -> Callable:
@@ -88,4 +88,7 @@ def pipeline_stack(
         out_specs=P(axis),
         check_rep=False,
     )(stage_params, x_micro)
-    return result[-1]
+    # the last stage's outputs, replicated: an Explicit-axis mesh (the
+    # jax.make_mesh default) refuses a plain index into a stage-sharded
+    # result without the sharding of what it returns
+    return result.at[-1].get(out_sharding=NamedSharding(mesh, P()))

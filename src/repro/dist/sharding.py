@@ -108,21 +108,35 @@ def constrain(x: jax.Array, *logical: Optional[str]) -> jax.Array:
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-def serve_mesh(min_devices: int = 2) -> Optional[Mesh]:
-    """Mesh for the batched serving engine: all local devices on one
-    ``data`` axis.
+def serve_mesh(devices: Optional[Sequence] = None,
+               min_devices: int = 2) -> Optional[Mesh]:
+    """Mesh for the batched serving engine: ``devices`` (default: all
+    local devices) on one ``data`` axis.
 
     Returns None on a single device (the engine runs unsharded — the common
     CPU/test case). With devices > 1 the engine traces its decode step and
     head GEMM under ``axis_rules(serve_mesh())``, so every ``batch``-tagged
-    activation — including the slot batch feeding the entangled head GEMM —
-    shards across devices; the entanglement groups stay device-local because
-    the group axis is folded out of the batch before the kernel call.
+    activation shards across devices. The entangled kernels cannot be
+    partitioned automatically: under the mesh each runs in a ``shard_map``
+    on every row against its device's slice of the weight columns
+    (``kernels/ops.py``), so all M entanglement groups meet on each device
+    and a stream's roll-forward needs nothing from another.
     """
-    n = jax.device_count()
-    if n < min_devices:
+    devs = list(jax.devices() if devices is None else devices)
+    if len(devs) < min_devices:
         return None
-    return Mesh(np.asarray(jax.devices()), ("data",))
+    return Mesh(np.asarray(devs), ("data",))
+
+
+def active_mesh() -> Optional[Mesh]:
+    """The :func:`axis_rules` mesh when it spans more than one device,
+    else None — what kernels that cannot be partitioned automatically
+    (Pallas TPU custom calls) check before wrapping themselves in a
+    ``shard_map``."""
+    mesh = _current_mesh()
+    if mesh is None or mesh.size == 1:
+        return None
+    return mesh
 
 
 def axis_extent(name: str) -> int:

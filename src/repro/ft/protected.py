@@ -153,7 +153,7 @@ def protected_matmul(
     xq, a_scale = quantize_acts(xf, plan, K)
     pad = (-R) % M
     if pad:
-        xq = jnp.concatenate([xq, jnp.zeros((pad, K), jnp.int32)], axis=0)
+        xq = jnp.concatenate([xq, jnp.zeros((pad, K), xq.dtype)], axis=0)
     Rp = R + pad
     if contiguous:
         inv = None
@@ -179,7 +179,7 @@ def protected_matmul(
                                           blocks=blocks, interpret=interpret,
                                           backend=backend)
         else:
-            eps = core_entangle(xg, plan)
+            eps = core_entangle(xg.astype(jnp.int32), plan)
             wq_full = unpack_int8(wq, axis=0, n=K) if packed else wq
             delta = jnp.einsum("mbk,kn->mbn", eps, wq_full).astype(jnp.int32)
         if failed_group is not None:
@@ -238,7 +238,7 @@ def protected_matmul_grouped(
     pad = (-R) % M
     if pad:
         xq = jnp.concatenate(
-            [xq, jnp.zeros((E, pad, K), jnp.int32)], axis=1)
+            [xq, jnp.zeros((E, pad, K), xq.dtype)], axis=1)
     Rp = R + pad
     order, inv = group_order(Rp, M)
     # per-expert round-robin onto streams: [E, Rp, K] -> [M, E, Rp/M, K]
@@ -257,7 +257,7 @@ def protected_matmul_grouped(
                 xg, wq, plan, packed=packed, blocks=blocks,
                 interpret=interpret, backend=backend)
         else:
-            eps = core_entangle(xg, plan)
+            eps = core_entangle(xg.astype(jnp.int32), plan)
             wq_full = unpack_int8(wq, axis=1, n=K) if packed else wq
             delta = jnp.einsum("meck,ekn->mecn", eps,
                                wq_full.astype(jnp.int32)).astype(jnp.int32)
@@ -332,7 +332,7 @@ def entangled_chain(
     xq, a_scale = quantize_acts(xf, plan, K, budget=budget)
     pad = (-R) % M
     if pad:
-        xq = jnp.concatenate([xq, jnp.zeros((pad, K), jnp.int32)], axis=0)
+        xq = jnp.concatenate([xq, jnp.zeros((pad, K), xq.dtype)], axis=0)
     Rp = R + pad
     if contiguous:
         inv = None
@@ -529,7 +529,7 @@ class FTContext:
         xq, a_scale = quantize_acts(xf, plan, K)
         pad = (-rows) % M
         if pad:
-            xq = jnp.concatenate([xq, jnp.zeros((pad, K), jnp.int32)],
+            xq = jnp.concatenate([xq, jnp.zeros((pad, K), xq.dtype)],
                                  axis=0)
         Rp = rows + pad
         order, inv = group_order(Rp, M)

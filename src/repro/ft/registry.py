@@ -50,11 +50,25 @@ def _pow2_cover(n: int, lo: int, hi: int) -> int:
     return p
 
 
-def default_blocks(Bg: int, K: int, N: int) -> dict:
+# smallest blocks per backend: the compiled TPU kernels feed int8 operands
+# to the MXU, and int8 blocks tile as (32, 128) — rows (bb) in 32s, the
+# contraction (bk) and output lanes (bn) in 128s
+_FLOORS = {"pallas_tpu": {"bb": 32, "bn": 128, "bk": 128}}
+_FLOOR_DEFAULT = {"bb": 8, "bn": 32, "bk": 32}
+
+
+def row_block(Bg: int, backend: Optional[str] = None) -> int:
+    """Row block (``bb``) covering ``Bg`` per-group rows on ``backend``."""
+    return _pow2_cover(Bg, _FLOORS.get(backend, _FLOOR_DEFAULT)["bb"], 128)
+
+
+def default_blocks(Bg: int, K: int, N: int,
+                   backend: Optional[str] = None) -> dict:
     """Shape-clamped block sizes for one (Bg, K, N) protected GEMM."""
-    return {"bb": _pow2_cover(Bg, 8, 128),
-            "bn": _pow2_cover(N, 32, 256),
-            "bk": _pow2_cover(K, 32, 256)}
+    lo = _FLOORS.get(backend, _FLOOR_DEFAULT)
+    return {"bb": row_block(Bg, backend),
+            "bn": _pow2_cover(N, lo["bn"], 256),
+            "bk": _pow2_cover(K, lo["bk"], 256)}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -122,7 +136,7 @@ class PlanRegistry:
         if e is None:
             blocks = self.blocks_policy
             if blocks is None:
-                blocks = default_blocks(*shape[-3:])
+                blocks = default_blocks(*shape[-3:], backend)
             e = ProtectionPlan(site=site, shape=shape, backend=backend,
                                plan=self.plan, blocks=blocks,
                                grouped=groups is not None,

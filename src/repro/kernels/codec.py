@@ -72,7 +72,9 @@ def pack_int8(x: jax.Array, axis: int = -2) -> jax.Array:
 
 def unpack_int8(p: jax.Array, axis: int = -2, n: Optional[int] = None
                 ) -> jax.Array:
-    """Inverse of :func:`pack_int8`: expand ``axis`` 1-to-4, sign-extended.
+    """Inverse of :func:`pack_int8`: expand ``axis`` 1-to-4 into int8 —
+    the MXU's operand type, so the fused kernels feed the result straight
+    to an int8 x int8 -> int32 dot.
 
     ``n`` truncates the unpacked axis back to its original length (the
     pack may have zero-padded it to a multiple of :data:`PACK_LANES`).
@@ -80,13 +82,76 @@ def unpack_int8(p: jax.Array, axis: int = -2, n: Optional[int] = None
     axis = axis % p.ndim
     lanes = [jnp.right_shift(jnp.left_shift(p, 24 - 8 * j), 24)
              for j in range(PACK_LANES)]
-    out = jnp.stack(lanes, axis=axis + 1)
+    out = jnp.stack(lanes, axis=axis + 1).astype(jnp.int8)
     shape = list(p.shape)
     shape[axis] = p.shape[axis] * PACK_LANES
     out = out.reshape(shape)
     if n is not None and n != out.shape[axis]:
         out = jax.lax.slice_in_dim(out, 0, n, axis=axis)
     return out
+
+
+# ---------------------------------------------------------------------------
+# int8 limbs — the MXU operand contract
+#
+# The TPU MXU multiplies int8 (or bf16) operands only; an int32 x int32 dot
+# does not lower. A GEMM operand wider than int8 is therefore split into
+# int8 limbs, x = sum_i limb_i << 8i, one limb per byte of its integer
+# type, and each limb gets its own int8 dot, recombined by shifts in the
+# int32 accumulator. Limbs are balanced ([-128, 127]): one holds an int8,
+# two an int16, and four hold ANY int32 exactly mod 2^32 (the top limb
+# wraps, and 2^24 * 256 == 2^32 vanishes) — the int32 accumulator's own
+# arithmetic, so a limb-split GEMM is bit-identical to the int32 GEMM it
+# replaces. The operand's dtype is its bound: callers that know their
+# values fit int8 (the eq.-13 activation grid) pass int8 and pay one limb.
+# ---------------------------------------------------------------------------
+
+
+def split_int8(x: jax.Array) -> jax.Array:
+    """Balanced int8 limbs of integer ``x``, one per byte of its dtype:
+    ``[n, *x.shape]`` int8 with ``x == sum_i limbs[i] << 8i`` (mod 2^32)."""
+    n = min(jnp.dtype(x.dtype).itemsize, 4)
+    x = x.astype(jnp.int32)
+    limbs = []
+    for _ in range(n - 1):
+        lo = jnp.bitwise_and(x + 128, 255) - 128
+        limbs.append(lo.astype(jnp.int8))
+        x = jnp.right_shift(x - lo, 8)  # exact: x - lo is a multiple of 256
+    limbs.append(x.astype(jnp.int8))
+    return jnp.stack(limbs)
+
+
+def limb_dot(c_limbs, g: jax.Array, shift: int = 0) -> jax.Array:
+    """``(sum_i c_limbs[i] << 8i) @ g << shift`` in int32, one int8 MXU
+    dot per limb. Terms shifted past bit 31 vanish mod 2^32 and are
+    skipped statically."""
+    acc = None
+    for i, limb in enumerate(c_limbs):
+        s = shift + 8 * i
+        if s >= 32:
+            break
+        p = jnp.left_shift(
+            jnp.dot(limb, g, preferred_element_type=jnp.int32), s)
+        acc = p if acc is None else acc + p
+    return acc
+
+
+def entangled_limb_dot(c_limbs, g: jax.Array, m: int, l: int,
+                       entangle: bool) -> jax.Array:
+    """Stream ``m``'s entangled product ``eps[m] @ g`` from int8 limbs
+    ``c_limbs`` ([n, M, ...]), where ``eps[m] = (c[m-1] << l) + c[m]``
+    (eq. 14/15) when ``entangle`` and ``c[m]`` otherwise. Linearity gives
+    ``(c[m-1] @ g << l) + c[m] @ g``. Stream m computes BOTH products
+    itself — sharing ``c[j] @ g`` between streams j and j+1 would let one
+    fail-stop corrupt two entangled outputs and void the single-failure
+    guarantee."""
+    M = c_limbs.shape[1]
+    acc = limb_dot([c_limbs[i, m] for i in range(c_limbs.shape[0])], g)
+    if entangle:
+        prev = (m - 1) % M
+        acc = acc + limb_dot(
+            [c_limbs[i, prev] for i in range(c_limbs.shape[0])], g, l)
+    return acc
 
 
 def disentangle_rows(

@@ -13,9 +13,16 @@ read and one fused pass:
 The M stream axis is fully resident per block (M is 3..8), so the cyclic
 predecessor is a register roll — the operand is bound once per tile role.
 
+Depthwise conv has no contraction, so it runs on the VPU (int32 lane
+multiplies), not the MXU: the int8 MXU contract of the GEMM kernels does
+not apply, and packed weights are unpacked straight to int8 lanes that
+widen in the multiply.
+
 Causality halo: each output tile of length ``bt`` needs ``K_f - 1``
 trailing inputs of the previous tile. Pallas blocks are uniform, so the
-input is bound a second time at index ``max(t-1, 0)`` for the halo, which
+input is bound a second time at index ``max(t-1, 0)`` for the halo; the
+taps rotate lanes instead of slicing them
+(:func:`repro.kernels.conv1d.causal_taps`). The second binding
 fetches a full extra tile per grid step (~2x input traffic) to use only
 its trailing K_f - 1 columns. Accepted: conv input bytes are a small share
 of a step's total traffic; carrying the previous tile's tail across grid
@@ -33,27 +40,24 @@ from jax.experimental import pallas as pl
 from repro.core.plan import EntanglePlan
 from repro.kernels.codec import (PACK_LANES, disentangle_block,
                                  entangle_block, unpack_int8)
+from repro.kernels.conv1d import causal_taps
 
 
 def _econv_kernel(
     x_cur_ref, x_prev_ref, w_ref, out_ref, *,
-    plan: EntanglePlan, kf: int, fuse_epilogue: bool, r: int, packed: bool,
+    plan: EntanglePlan, fuse_epilogue: bool, r: int, packed: bool,
 ):
     t = pl.program_id(2)
-    M, l = plan.M, plan.l
+    l = plan.l
 
     eps_cur = entangle_block(x_cur_ref[:, 0], l)  # [M, bd, bt]
-    eps_halo = entangle_block(x_prev_ref[:, 0, :, -(kf - 1):], l)
-    eps_halo = jnp.where(t == 0, jnp.zeros_like(eps_halo), eps_halo)
+    eps_prev = entangle_block(x_prev_ref[:, 0], l)
+    eps_prev = jnp.where(t == 0, jnp.zeros_like(eps_prev), eps_prev)
 
-    window = jnp.concatenate([eps_halo, eps_cur], axis=-1)  # [M, bd, bt+kf-1]
-    bt = out_ref.shape[-1]
-    acc = jnp.zeros(out_ref.shape[:1] + out_ref.shape[2:], jnp.int32)
     w = w_ref[...]
     if packed:  # [bd/4, kf] words -> [bd, kf] sign-extended lanes
         w = unpack_int8(w, axis=0)
-    for j in range(kf):  # static unroll over taps
-        acc += w[None, :, j : j + 1] * window[:, :, j : j + bt]
+    acc = causal_taps(eps_cur, eps_prev, w.astype(jnp.int32))
 
     if fuse_epilogue:
         acc = disentangle_block(acc, plan, r)
@@ -96,7 +100,7 @@ def entangled_conv1d_pallas(
     bdg = bd // PACK_LANES if packed else bd
     return pl.pallas_call(
         functools.partial(
-            _econv_kernel, plan=plan, kf=kf,
+            _econv_kernel, plan=plan,
             fuse_epilogue=fuse_epilogue, r=failed % M, packed=packed,
         ),
         grid=grid,

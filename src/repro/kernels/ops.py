@@ -49,11 +49,13 @@ registrations at the bottom of this module)::
         "entangled_matmul_grouped": my_triton_emmg, #  failed, blocks,
     }, interpret=False)                             #  packed)
 
-Each callable receives block-multiple-padded int32 operands and the
-resolved ``blocks`` dict and must reproduce the reference oracle
-bit-exactly (``tests/test_fused_codec.py`` parametrizes over registered
-backends' semantics; the codec is shifts/adds, so any backend that
-accumulates in int32 matches). :func:`triton_cuda_stub` returns a
+Each callable receives block-multiple-padded integer operands (GEMM
+activations keep an int8/int16 dtype, which bounds them; everything else
+is int32; a GEMM's output columns N are left unpadded, so its last column
+block may be partial) and the resolved ``blocks`` dict and must reproduce
+the reference oracle bit-exactly (``tests/test_fused_codec.py``
+parametrizes over registered backends' semantics; the codec is
+shifts/adds, so any backend that accumulates in int32 matches). :func:`triton_cuda_stub` returns a
 placeholder impls dict whose entries raise ``NotImplementedError`` with
 these porting notes — register it to reserve the namespace before the
 kernels exist. Pre-tuned block sizes ship per backend as
@@ -69,6 +71,7 @@ from typing import Callable, Mapping, Optional, Union
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.plan import EntanglePlan
 from repro.kernels import autotune as at
@@ -182,6 +185,34 @@ def resolve_backend(backend: Optional[str] = None,
     if _DEFAULT is not None:
         return _DEFAULT
     return "pallas_tpu" if jax.default_backend() == "tpu" else "interpret_cpu"
+
+
+def _gemm_operand(c: jax.Array) -> jax.Array:
+    """GEMM activations keep an int8/int16 dtype — it bounds their values,
+    and the Pallas kernels split them into that many int8 MXU limbs (one
+    for int8); any other dtype widens to int32 (four limbs, exact for any
+    value)."""
+    return c if c.dtype in (jnp.int8, jnp.int16) else c.astype(jnp.int32)
+
+
+def _columns_sharded(run: Callable, g_ndim: int, out_ndim: int):
+    """``run(c, g)`` as one kernel per device when a multi-device
+    :func:`repro.dist.sharding.axis_rules` mesh is active, else ``run``
+    itself, plus the device count. A Pallas TPU kernel cannot be
+    partitioned automatically, so under a mesh each device runs the kernel
+    on every row (``c`` replicated) against its own slice of the weights'
+    output columns (the last axis of ``g`` and of the output) — the codec
+    is elementwise over columns, so extraction stays device-local and a
+    stream's roll-forward needs nothing from another device."""
+    from repro.dist.sharding import active_mesh  # deferred: no cycle
+
+    mesh = active_mesh()
+    if mesh is None:
+        return run, 1
+    cols = tuple(mesh.axis_names)
+    spec = lambda nd: P(*([None] * (nd - 1)), cols)  # noqa: E731
+    return jax.shard_map(run, mesh=mesh, in_specs=(P(), spec(g_ndim)),
+                         out_specs=spec(out_ndim), check_vma=False), mesh.size
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int):
@@ -312,26 +343,30 @@ def entangled_matmul(c: jax.Array, g: jax.Array, plan: EntanglePlan, *,
     _check_fuse(fuse_epilogue, chain_ok=True)
     M, B, K = c.shape
     N = g.shape[1]
-    c32 = c.astype(jnp.int32)
+    c_op = _gemm_operand(c)
     g32 = g.astype(jnp.int32)
     bname = resolve_backend(backend, interpret)
     impl = get_backend(bname).impls["entangled_matmul"]
     r = 0 if failed is None else failed
 
     def call(bl, cc, gg):
+        run, shards = _columns_sharded(
+            lambda c, g: impl(c, g, plan=plan, fuse_epilogue=fuse_epilogue,
+                              failed=r, blocks=bl, packed=packed), 2, 3)
         cp, _ = _pad_to(cc, 1, bl["bb"])
         cp, _ = _pad_to(cp, 2, bl["bk"])
         # packed weights pad along K in words (bk/4 words == bk lanes)
         gp, _ = _pad_to(gg, 0, bl["bk"] // PACK_LANES if packed else bl["bk"])
-        gp, _ = _pad_to(gp, 1, bl["bn"])
-        return impl(cp, gp, plan=plan, fuse_epilogue=fuse_epilogue,
-                    failed=r, blocks=bl, packed=packed)
+        # N is never padded to bn (the kernels take a partial last column
+        # block), only to an even split over the devices
+        gp, _ = _pad_to(gp, 1, shards)
+        return run(cp, gp)
 
     bl = _resolve_blocks(
         "entangled_matmul", {"bb": bb, "bn": bn, "bk": bk}, blocks,
-        (M, B, K, N), bname, lambda b: (lambda: call(b, c32, g32)),
+        (M, B, K, N), bname, lambda b: (lambda: call(b, c_op, g32)),
         flags=_matmul_flags(plan, fuse_epilogue, packed))
-    out = call(bl, c32, g32)
+    out = call(bl, c_op, g32)
     return out[:, :B, :N]
 
 
@@ -357,25 +392,27 @@ def entangled_matmul_grouped(c: jax.Array, g: jax.Array, plan: EntanglePlan,
     _check_fuse(fuse_epilogue, chain_ok=False)
     M, E, Cg, K = c.shape
     N = g.shape[2]
-    c32 = c.astype(jnp.int32)
+    c_op = _gemm_operand(c)
     g32 = g.astype(jnp.int32)
     bname = resolve_backend(backend, interpret)
     impl = get_backend(bname).impls["entangled_matmul_grouped"]
     r = 0 if failed is None else failed
 
     def call(bl, cc, gg):
+        run, shards = _columns_sharded(
+            lambda c, g: impl(c, g, plan=plan, fuse_epilogue=fuse_epilogue,
+                              failed=r, blocks=bl, packed=packed), 3, 4)
         cp, _ = _pad_to(cc, 2, bl["bb"])
         cp, _ = _pad_to(cp, 3, bl["bk"])
         gp, _ = _pad_to(gg, 1, bl["bk"] // PACK_LANES if packed else bl["bk"])
-        gp, _ = _pad_to(gp, 2, bl["bn"])
-        return impl(cp, gp, plan=plan, fuse_epilogue=fuse_epilogue,
-                    failed=r, blocks=bl, packed=packed)
+        gp, _ = _pad_to(gp, 2, shards)  # N: see entangled_matmul
+        return run(cp, gp)
 
     bl = _resolve_blocks(
         "entangled_matmul_grouped", {"bb": bb, "bn": bn, "bk": bk}, blocks,
-        (M, E, Cg, K, N), bname, lambda b: (lambda: call(b, c32, g32)),
+        (M, E, Cg, K, N), bname, lambda b: (lambda: call(b, c_op, g32)),
         flags=_matmul_flags(plan, fuse_epilogue, packed))
-    out = call(bl, c32, g32)
+    out = call(bl, c_op, g32)
     return out[:, :, :Cg, :N]
 
 
@@ -398,6 +435,16 @@ def _matmul_flags(plan: EntanglePlan, fuse_epilogue,
     return flags
 
 
+def _warm_acts_dtype(plan: EntanglePlan, K: int, fuse_epilogue):
+    """Activation dtype of a protected GEMM with a ``K``-deep contraction:
+    its eq.-13 grid's (int8 at published widths, one MXU limb); the chain
+    modes carry entangled int32 accumulators (four limbs)."""
+    from repro.ft.quantize import acts_dtype  # deferred: ft imports ops
+
+    return jnp.int32 if fuse_epilogue in _FUSE_MODES[2:] else acts_dtype(
+        plan, K)
+
+
 def warm_entangled_matmul(M: int, B: int, K: int, N: int, plan: EntanglePlan,
                           *, fuse_epilogue=True, packed: bool = False,
                           interpret=None,
@@ -409,9 +456,12 @@ def warm_entangled_matmul(M: int, B: int, K: int, N: int, plan: EntanglePlan,
     inside the engine's jitted decode step is a pure in-process cache hit
     (a sweep during tracing would time tracers, not kernels). ``failed`` is
     deliberately not part of the autotune key, so one warm covers healthy
-    and every fail-stop-injected variant. Returns the winning block sizes.
+    and every fail-stop-injected variant. The sweep's activations take the
+    dtype the protected path quantizes to (:func:`_warm_acts_dtype`), so
+    it times the limb count that serving runs. Returns the winning block
+    sizes.
     """
-    c = jnp.zeros((M, B, K), jnp.int32)
+    c = jnp.zeros((M, B, K), _warm_acts_dtype(plan, K, fuse_epilogue))
     Kg = -(-K // PACK_LANES) if packed else K
     g = jnp.zeros((Kg, N), jnp.int32)
     entangled_matmul(c, g, plan, fuse_epilogue=fuse_epilogue, packed=packed,
@@ -429,7 +479,7 @@ def warm_entangled_matmul_grouped(M: int, E: int, Cg: int, K: int, N: int,
                                   backend: Optional[str] = None) -> dict:
     """Grouped twin of :func:`warm_entangled_matmul` for the MoE
     per-expert shapes of the engine census."""
-    c = jnp.zeros((M, E, Cg, K), jnp.int32)
+    c = jnp.zeros((M, E, Cg, K), _warm_acts_dtype(plan, K, fuse_epilogue))
     Kg = -(-K // PACK_LANES) if packed else K
     g = jnp.zeros((E, Kg, N), jnp.int32)
     entangled_matmul_grouped(c, g, plan, fuse_epilogue=fuse_epilogue,
@@ -536,6 +586,7 @@ def _ref_impls() -> dict:
     plain per-stream GEMM on the already-entangled input (linearity:
     ``(E c) @ g = E (c @ g)``), extracting only in ``'chain_final'``."""
     def emm(c, g, *, plan, fuse_epilogue, failed, blocks, packed=False):
+        c = c.astype(jnp.int32)
         if packed:
             g = codec.unpack_int8(g, axis=0)
         if fuse_epilogue in ("chain", "chain_final"):
@@ -550,6 +601,7 @@ def _ref_impls() -> dict:
         return ref.entangled_matmul_ref(c, g, plan.l)
 
     def emmg(c, g, *, plan, fuse_epilogue, failed, blocks, packed=False):
+        c = c.astype(jnp.int32)
         if packed:
             g = codec.unpack_int8(g, axis=1)
         if fuse_epilogue:

@@ -50,6 +50,7 @@ import jax
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.ft import SCOPES
 from repro.kernels import autotune
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
 from repro.serve.engine import Request, ServeConfig, ServeEngine
 from repro.serve.fleet import Fleet, FleetConfig, ScalingPolicy
@@ -307,6 +308,7 @@ def main():
                          "healthy replica")
     args = ap.parse_args()
     buckets = _validate_args(ap, args)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = get_model(cfg)

@@ -2,8 +2,10 @@
 
 Runs the production train step on whatever devices exist (CPU dev loop, or a
 real TPU slice where the same code path scales to the dry-run meshes). On
-TPU, XLA latency-hiding flags below overlap FSDP all-gathers / gradient
-reduce-scatters with compute — set before jax initializes.
+TPU, the latency-hiding flags below (``REPRO_TPU_FLAGS=1``) overlap FSDP
+all-gathers / gradient reduce-scatters with compute. They are TPU compiler
+flags, so they are appended to ``LIBTPU_INIT_ARGS`` (never replacing what
+it already holds) before jax initializes.
 """
 import argparse
 import os
@@ -14,13 +16,15 @@ TPU_PERF_FLAGS = (
     "--xla_tpu_overlap_compute_collective_tc=true "
 )
 if os.environ.get("REPRO_TPU_FLAGS", "0") == "1":
-    os.environ["XLA_FLAGS"] = TPU_PERF_FLAGS + os.environ.get("XLA_FLAGS", "")
+    os.environ["LIBTPU_INIT_ARGS"] = (
+        os.environ.get("LIBTPU_INIT_ARGS", "") + " " + TPU_PERF_FLAGS).strip()
 
 import jax  # noqa: E402
 
 from repro.configs import ARCH_IDS, get_config, get_smoke_config  # noqa: E402
 from repro.data.synthetic import DataConfig  # noqa: E402
 from repro.dist.sharding import axis_rules  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_local_mesh  # noqa: E402
 from repro.optim.adamw import AdamWConfig  # noqa: E402
 from repro.train.train_step import TrainConfig  # noqa: E402
@@ -40,6 +44,7 @@ def main():
     ap.add_argument("--grad-accum", type=int, default=1)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_launch_train")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     tcfg = TrainConfig(

@@ -126,7 +126,8 @@ a traced prefill.
 
 On hosts with more than one device the decode step traces under
 ``dist.sharding.serve_mesh()``, sharding the slot batch (and the head GEMM)
-across devices.
+across devices; ``ServeEngine(..., devices=[d])`` confines an engine to
+one device instead.
 """
 from __future__ import annotations
 
@@ -138,16 +139,17 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core.plan import make_plan
 from repro.dist import sharding
 from repro.ft import (SCOPES, FTContext, PlanRegistry, compile_plans,
                       prepare_params)
-from repro.ft.heads import (ft_logits_decode, ft_logits_prefill,
-                            quantize_head)
+from repro.ft.quantize import quantize_weight_stacked
+from repro.ft.registry import row_block
+from repro.ft.heads import ft_logits_decode, ft_logits_prefill
 from repro.kernels import ops as kops
-from repro.kernels.codec import pack_int8
 from repro.models.api import get_model
 from repro.models.layers import ACT_DTYPE
 from repro.models.transformer import readout_scale
@@ -249,7 +251,11 @@ class Request:
 
 class ServeEngine:
     def __init__(self, cfg: ModelConfig, scfg: ServeConfig, params,
-                 warm: Optional[dict] = None):
+                 warm: Optional[dict] = None,
+                 devices: Optional[Sequence] = None):
+        """``devices`` (default: every visible device) are the devices the
+        slot batch shards across, with the weights replicated on each; a
+        single device runs the engine unsharded there."""
         self.cfg, self.scfg, self.params = cfg, scfg, params
         if not scfg.greedy:
             raise NotImplementedError("only greedy decode is implemented")
@@ -272,7 +278,14 @@ class ServeEngine:
         self.census: dict[str, dict] = {"prefill": {}, "decode": {}}
         self.decode_calls = 0  # jitted decode invocations (one per step)
         self.prefill_calls = 0  # jitted prefill invocations (chunk/packed)
-        self.mesh = sharding.serve_mesh()
+        self.mesh = sharding.serve_mesh(devices)
+        if self.mesh is not None:
+            # replicate the weights onto the mesh ONCE: uncommitted
+            # weights would be copied to every device on every step
+            params = jax.device_put(params, NamedSharding(self.mesh, P()))
+        elif devices is not None:
+            params = jax.device_put(params, list(devices)[0])
+        self.params = params
 
         # admission pipeline configuration
         self.buckets = resolve_buckets(scfg)
@@ -371,14 +384,12 @@ class ServeEngine:
                 # admission-batch head projection, every in-model protected
                 # site and every autotune key
                 self.plan = make_plan(scfg.ft_M, scfg.ft_w)
-                self.head_q, self.w_scale = quantize_head(
-                    self.model.head_weights(params, cfg))
-                # true [D, V] head dims — recorded BEFORE packing (the
-                # packed copy's contraction axis holds ceil(D/4) words,
-                # not D)
-                self._head_dims = tuple(self.head_q.shape)
-                if scfg.ft_packed:
-                    self.head_q = pack_int8(self.head_q, axis=0)
+                head = self.model.head_weights(params, cfg)
+                # true [D, V] head dims — the packed copy's contraction
+                # axis holds ceil(D/4) words, not D
+                self._head_dims = tuple(head.shape)
+                q8 = quantize_weight_stacked(head, packed=scfg.ft_packed)
+                self.head_q, self.w_scale = q8["w"], q8["scale"]
                 # the protected-GEMM subsystem: one registry for the whole
                 # forward pass; layer sites get "auto" blocks only when the
                 # engine itself autotunes (a user dict targets the HEAD
@@ -542,14 +553,12 @@ class ServeEngine:
         """Head-GEMM block sizes when the user gave none: the per-group
         decode batch is tiny (max_batch / M rows), so the wrapper's
         MXU-aligned bb=128 default would pad it ~64x with zero rows every
-        step — clamp bb to the smallest power of two covering the group."""
+        step — clamp bb to the smallest legal power of two covering the
+        group."""
         if self.scfg.blocks is not None or self.scfg.ft_mode != "entangle":
             return self.scfg.blocks
         gsz = self.scfg.max_batch // self.scfg.ft_M
-        bb = 8
-        while bb < min(gsz, 128):
-            bb *= 2
-        return {"bb": bb}
+        return {"bb": row_block(gsz, kops.resolve_backend())}
 
     # -- jitted programs ------------------------------------------------------
 

@@ -100,3 +100,32 @@ def test_new_scopes_accepted_at_parse_time(monkeypatch, capsys):
             launch_serve.main()
         assert e.value.code == 2
         assert "prefill-chunk" in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("env_set", [False, True], ids=["fixed", "env"])
+def test_compile_cache_placement(monkeypatch, tmp_path, env_set):
+    """The entry points' compile cache: JAX_COMPILATION_CACHE_DIR, where
+    set, is left to JAX (no other directory is configured); otherwise the
+    cache goes to ``<checkout>/.jax_cache``."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    env_dir = str(tmp_path / "from_env")
+    if env_set:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = enable_compile_cache(tmp_path)
+        if env_set:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            assert got == str(tmp_path / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        cc.reset_cache()
