@@ -45,6 +45,11 @@ static ``failed_group`` of the current traced program.  Site names are
 ``ft_scope`` widens protection cumulatively: ``"head"`` | ``"qkv"`` |
 ``"mlp"`` | ``"out"`` | ``"moe"`` (each includes the head) | ``"all"`` —
 which, since v2, genuinely covers every hot-path GEMM.
+
+Each protected call traces under ``jax.named_scope("ft.<site>")`` (a
+fanout group's shared codec pass under ``ft.<site>+<site>...``), so each
+compiled operation's metadata (``op_name``) names its site; the scopes
+change no operation.
 """
 from __future__ import annotations
 
@@ -492,8 +497,9 @@ class FTContext:
             return jnp.einsum("...k,kn->...n", x.astype(jnp.float32),
                               _unpacked_f32(wq, K, axis=0))
         plan = self._resolve(site, rows, K, N)
-        return ProtectedLinear(plan=plan, use_pallas=self.use_pallas)(
-            x, w, failed_group=self.failed_group)
+        with jax.named_scope(f"ft.{site}"):
+            return ProtectedLinear(plan=plan, use_pallas=self.use_pallas)(
+                x, w, failed_group=self.failed_group)
 
     def matmul_fanout(self, sites: tuple, x: jax.Array,
                       ws: tuple) -> list:
@@ -525,29 +531,32 @@ class FTContext:
         plan = plans[0].plan
         M = plan.M
         lead = x.shape[:-1]
-        xf = x.reshape(rows, K).astype(jnp.float32)
-        xq, a_scale = quantize_acts(xf, plan, K)
-        pad = (-rows) % M
-        if pad:
-            xq = jnp.concatenate([xq, jnp.zeros((pad, K), xq.dtype)],
-                                 axis=0)
-        Rp = rows + pad
-        order, inv = group_order(Rp, M)
-        xg = xq[order].reshape(M, Rp // M, K)
+        # the shared codec pass is named by the whole group
+        with jax.named_scope("ft." + "+".join(sites)):
+            xf = x.reshape(rows, K).astype(jnp.float32)
+            xq, a_scale = quantize_acts(xf, plan, K)
+            pad = (-rows) % M
+            if pad:
+                xq = jnp.concatenate([xq, jnp.zeros((pad, K), xq.dtype)],
+                                     axis=0)
+            Rp = rows + pad
+            order, inv = group_order(Rp, M)
+            xg = xq[order].reshape(M, Rp // M, K)
 
         from repro.kernels import ops as kops
 
         outs = []
-        for p, w in zip(plans, ws):
-            wq_i, w_scale = _split_weight(w)
-            N = wq_i.shape[-1]
-            rec = kops.entangled_matmul(
-                xg, wq_i, p.plan, fuse_epilogue=True,
-                failed=self.failed_group, packed=_is_packed(wq_i, K),
-                blocks=p.blocks, backend=p.backend)
-            y = rec.reshape(Rp, N).astype(jnp.float32)
-            y = y[inv][:rows] / (a_scale * w_scale)
-            outs.append(y.reshape(*lead, N))
+        for s, p, w in zip(sites, plans, ws):
+            with jax.named_scope(f"ft.{s}"):
+                wq_i, w_scale = _split_weight(w)
+                N = wq_i.shape[-1]
+                rec = kops.entangled_matmul(
+                    xg, wq_i, p.plan, fuse_epilogue=True,
+                    failed=self.failed_group, packed=_is_packed(wq_i, K),
+                    blocks=p.blocks, backend=p.backend)
+                y = rec.reshape(Rp, N).astype(jnp.float32)
+                y = y[inv][:rows] / (a_scale * w_scale)
+                outs.append(y.reshape(*lead, N))
         return outs
 
     def matmul_grouped(self, site: str, x: jax.Array,
@@ -563,5 +572,6 @@ class FTContext:
             return jnp.einsum("...eck,ekn->...ecn", x.astype(jnp.float32),
                               _unpacked_f32(wq, K, axis=1))
         plan = self._resolve(site, rows, K, N, groups=E)
-        return ProtectedLinear(plan=plan, use_pallas=self.use_pallas)(
-            x, w, failed_group=self.failed_group)
+        with jax.named_scope(f"ft.{site}"):
+            return ProtectedLinear(plan=plan, use_pallas=self.use_pallas)(
+                x, w, failed_group=self.failed_group)

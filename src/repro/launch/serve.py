@@ -39,8 +39,16 @@ router migrates R's in-flight requests and the wave still completes),
 ``--max-replicas M --scale-up-depth D`` turns on queue-depth autoscaling
 between the initial pool size and M. All cross-flag contracts are
 validated at parse time.
+
+Tracing: ``--trace-dir DIR`` turns the engine's host spans on
+(:mod:`repro.serve.spans`), writes a JAX profiler trace of the wave to
+DIR (the spans appear on its host plane beside the device timeline) and
+prints the engine's start-up seconds, the median wait from submit to
+admission, and the mean host milliseconds per step (each ``serve.step``
+less its blocking device reads). Single-engine waves only.
 """
 import argparse
+import contextlib
 import dataclasses
 import time
 
@@ -54,6 +62,7 @@ from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
 from repro.serve.engine import Request, ServeConfig, ServeEngine
 from repro.serve.fleet import Fleet, FleetConfig, ScalingPolicy
+from repro.serve.spans import SpanLog
 from repro.train.checkpoint import CheckpointManager
 
 # shared drain bound for closed waves — kill/scaling schedules are
@@ -98,6 +107,18 @@ def _wave(eng: ServeEngine, n_requests: int, vocab: int, max_new: int,
               f"{sum(r.status == 'shed' for r in reqs)} queued requests "
               f"past --deadline-ms {deadline_ms}")
     return {r.rid: np.asarray(r.out) for r in reqs if r.status == "done"}
+
+
+def _print_spans(eng: ServeEngine, spans: SpanLog, trace_dir: str) -> None:
+    waits = [r.t_admitted - r.t_submit for r in eng.done
+             if r.t_admitted is not None]
+    host = spans.step_host_s()
+    print(f"[launch.serve] spans: engine init "
+          f"{sum(spans.durations('serve.init'))} s; median admit wait "
+          f"{np.median(waits) * 1e3 if waits else None} ms over "
+          f"{len(waits)} requests; host "
+          f"{np.mean(host) * 1e3 if host else None} ms per step over "
+          f"{len(host)} steps; profiler trace in {trace_dir}")
 
 
 def _fleet_wave(cfg, scfg: ServeConfig, params, args, failed_group):
@@ -236,6 +257,10 @@ def _validate_args(ap: argparse.ArgumentParser, args) -> None:
     elif args.kill_replica:
         ap.error(f"--kill-replica ({args.kill_replica}) requires "
                  f"--kill-replica-at to schedule the fail-stop")
+    if args.trace_dir and (args.replicas > 1 or args.max_replicas
+                           or args.kill_replica_at >= 0):
+        ap.error("--trace-dir traces a single-engine wave; it takes no "
+                 "fleet flags")
     return buckets
 
 
@@ -306,6 +331,10 @@ def main():
                     help="autoscaling trigger: spawn a replica when the "
                          "router queue exceeds this many requests per "
                          "healthy replica")
+    ap.add_argument("--trace-dir", default="",
+                    help="record the engine's host spans, write a profiler "
+                         "trace of the wave here and print the span "
+                         "summary")
     args = ap.parse_args()
     buckets = _validate_args(ap, args)
     enable_compile_cache()
@@ -333,10 +362,15 @@ def main():
         _fleet_wave(cfg, scfg, params, args, failed)
         return
 
-    eng = ServeEngine(cfg, scfg, params)
-    outs = _wave(eng, args.requests, cfg.vocab_size, args.max_new, failed,
-                 arrival_rate=args.arrival_rate,
-                 deadline_ms=args.deadline_ms)
+    spans = SpanLog() if args.trace_dir else None
+    eng = ServeEngine(cfg, scfg, params, spans=spans)
+    with (jax.profiler.trace(args.trace_dir) if spans is not None
+          else contextlib.nullcontext()):
+        outs = _wave(eng, args.requests, cfg.vocab_size, args.max_new,
+                     failed, arrival_rate=args.arrival_rate,
+                     deadline_ms=args.deadline_ms)
+    if spans is not None:
+        _print_spans(eng, spans, args.trace_dir)
     first = list(outs[0][:8]) if 0 in outs else "<request 0 not completed>"
     print(f"[launch.serve] {len(outs)}/{args.requests} requests completed in "
           f"{eng.decode_calls} batched decode calls; first output: {first}")

@@ -124,6 +124,13 @@ prefill-admission shape census, so the in-jit ``blocks='auto'`` resolution
 is a pure cache hit — sweeps must never run inside a traced decode step or
 a traced prefill.
 
+Host spans: ``ServeEngine(..., spans=SpanLog())`` times the start-up
+phases and the phases of every ``step`` (plan, pack, prefill, land,
+flush, decode, the two blocking device reads, emit) on the engine clock
+and as profiler annotations (:mod:`repro.serve.spans`); off by default.
+``Request.t_admitted`` (always stamped) splits a request's time to first
+token into its wait for admission and its admission pipeline.
+
 On hosts with more than one device the decode step traces under
 ``dist.sharding.serve_mesh()``, sharding the slot batch (and the head GEMM)
 across devices; ``ServeEngine(..., devices=[d])`` confines an engine to
@@ -154,6 +161,7 @@ from repro.models.api import get_model
 from repro.models.layers import ACT_DTYPE
 from repro.models.transformer import readout_scale
 from repro.serve.scheduler import (ChunkScheduler, RequestHandle, TokenRing)
+from repro.serve.spans import OFF, SpanLog
 
 
 def geometric_buckets(max_seq: int, base: int = 8) -> tuple:
@@ -244,6 +252,8 @@ class Request:
     # queued | prefill | decoding | done | cancelled | shed
     status: str = "new"
     t_submit: float = 0.0
+    # first admission into a prefill batch (the end of the queue wait)
+    t_admitted: Optional[float] = None
     t_first: Optional[float] = None  # first-token wall time (TTFT source)
     t_done: Optional[float] = None
     tok_times: list = dataclasses.field(default_factory=list)
@@ -252,10 +262,22 @@ class Request:
 class ServeEngine:
     def __init__(self, cfg: ModelConfig, scfg: ServeConfig, params,
                  warm: Optional[dict] = None,
-                 devices: Optional[Sequence] = None):
+                 devices: Optional[Sequence] = None,
+                 spans: Optional[SpanLog] = None):
         """``devices`` (default: every visible device) are the devices the
         slot batch shards across, with the weights replicated on each; a
-        single device runs the engine unsharded there."""
+        single device runs the engine unsharded there. ``spans`` records
+        the engine's host spans (:mod:`repro.serve.spans`); None = off."""
+        self._spans = spans
+        if spans is not None:
+            spans.clock = scfg.clock or time.monotonic
+        with self._span("serve.init"):
+            self._start(cfg, scfg, params, warm, devices)
+
+    def _span(self, name: str):
+        return OFF if self._spans is None else self._spans.span(name)
+
+    def _start(self, cfg, scfg, params, warm, devices):
         self.cfg, self.scfg, self.params = cfg, scfg, params
         if not scfg.greedy:
             raise NotImplementedError("only greedy decode is implemented")
@@ -388,7 +410,9 @@ class ServeEngine:
                 # true [D, V] head dims — the packed copy's contraction
                 # axis holds ceil(D/4) words, not D
                 self._head_dims = tuple(head.shape)
-                q8 = quantize_weight_stacked(head, packed=scfg.ft_packed)
+                with self._span("serve.init.quantize"):
+                    q8 = quantize_weight_stacked(head,
+                                                 packed=scfg.ft_packed)
                 self.head_q, self.w_scale = q8["w"], q8["scale"]
                 # the protected-GEMM subsystem: one registry for the whole
                 # forward pass; layer sites get "auto" blocks only when the
@@ -463,7 +487,8 @@ class ServeEngine:
             if self.plans is not None:
                 self.ftx = self.ftx.with_plans(self.plans)
             return
-        self.protected_census = self._protected_shape_census()
+        with self._span("serve.init.census"):
+            self.protected_census = self._protected_shape_census()
         # every chunk width any admission — boundary or refill — can run:
         # refill-time plan reuse is checked against this set, because a
         # refilled batch replays one of exactly these census'd programs
@@ -471,16 +496,20 @@ class ServeEngine:
         self.plans = None
         self.ft_params = params
         if scfg.ft_mode == "entangle" and scfg.ft_scope != "head":
-            self.plans = compile_plans(self.registry, self.protected_census)
-            # census / compile drift fails loudly at startup — a lazy
-            # mid-serve plan entry would mean refill retraced a shape the
-            # startup census missed
-            self.plans.assert_covers(self.protected_census)
+            with self._span("serve.init.plans"):
+                self.plans = compile_plans(self.registry,
+                                           self.protected_census)
+                # census / compile drift fails loudly at startup — a lazy
+                # mid-serve plan entry would mean refill retraced a shape
+                # the startup census missed
+                self.plans.assert_covers(self.protected_census)
             self.ftx = self.ftx.with_plans(self.plans)
-            self.ft_params = prepare_params(params, scope=scfg.ft_scope,
-                                            packed=scfg.ft_packed)
+            with self._span("serve.init.params"):
+                self.ft_params = prepare_params(params, scope=scfg.ft_scope,
+                                                packed=scfg.ft_packed)
         if scfg.blocks == "auto":
-            self.warm_autotune()
+            with self._span("serve.init.autotune"):
+                self.warm_autotune()
 
     def _warm_sig(self) -> tuple:
         """Config signature warm-started replicas must share. The clock is
@@ -778,10 +807,13 @@ class ServeEngine:
             self.metrics["refill_admissions"] += 1
         tokens = np.zeros((self.Bp, b0), np.int32)
         lengths = np.zeros(self.Bp, np.int32)
+        now = self._clock()
         for j, req in enumerate(take):
             tokens[j, : len(req.prompt)] = req.prompt
             lengths[j] = len(req.prompt)
             req.status = "prefill"
+            if req.t_admitted is None:
+                req.t_admitted = now
         slots = free[: len(take)]
         self._reserved.update(slots)
         self._inflight.append({
@@ -818,9 +850,11 @@ class ServeEngine:
         # in _prefill_head)
         fg = (failed_group if self._model_ft(failed_group) is not None
               else None)
-        p["h_last"], p["cache"] = chunk_fn(
-            self.ft_params, p["tokens"][:, pos0 : pos0 + sz], p["cache"],
-            p["lengths"], p["h_last"], pos0=pos0, failed_group=fg)
+        with self._span("serve.prefill"):
+            p["h_last"], p["cache"] = chunk_fn(
+                self.ft_params, p["tokens"][:, pos0 : pos0 + sz],
+                p["cache"], p["lengths"], p["h_last"], pos0=pos0,
+                failed_group=fg)
         self.prefill_calls += 1
         p["pos0"] = pos0 + sz
         if p["pos0"] < Tb:
@@ -828,7 +862,8 @@ class ServeEngine:
         # census records BUCKET shapes (admission rows, padded length) —
         # the traced call signature — never raw prompt lengths
         self._census_bump("prefill", (self.Bp, Tb))
-        self._land(p, failed_group)
+        with self._span("serve.land"):
+            self._land(p, failed_group)
 
     def _land(self, p: dict, failed_group: Optional[int]):
         """Land a COMPLETE admission batch (``p["cache"]`` / ``p["h_last"]``
@@ -843,9 +878,11 @@ class ServeEngine:
         vfull[: len(valid)] = valid
         head = (None if self.scfg.ft_mode != "entangle"
                 else (self.head_q, self.w_scale))
-        first = np.asarray(self._prefill_head(
+        first = self._prefill_head(
             self.ft_params, p["h_last"], jnp.asarray(vfull), head,
-            failed_group=failed_group))
+            failed_group=failed_group)
+        with self._span("serve.land.sync"):
+            first = np.asarray(first)
         sids = [i for i, _ in p["reqs"]]
         vrows, zero = list(valid), [False] * len(sids)
         merge = [i for i in self._dirty
@@ -891,37 +928,18 @@ class ServeEngine:
         in a single program, then land every batch whose live rows have
         all finished (cancelled rows pack nothing and all-cancelled
         batches drain without compute). Returns True if any row packed."""
-        rows = self.sched.pack_rows(self._inflight, self.Rp)
+        with self._span("serve.pack"):
+            rows = self.sched.pack_rows(self._inflight, self.Rp)
+            block = self._pack_block(rows) if rows else None
         if rows:
-            tok = np.zeros((self.Rp, self.Cp), np.int32)
-            sids = np.zeros(self.Rp, np.int32)
-            pos0r = np.zeros(self.Rp, np.int32)
-            lens = np.zeros(self.Rp, np.int32)
-            valid = np.zeros(self.Rp, bool)
-            used = []
-            true_toks = 0
-            for r, (p, i) in enumerate(rows):
-                off = int(p["rowpos"][i])
-                n = min(self.Cp, int(p["lengths_np"][i]) - off)
-                tok[r, :n] = p["tokens_np"][i, off : off + n]
-                sids[r] = p["reqs"][i][0]
-                pos0r[r] = off
-                lens[r] = p["lengths_np"][i]
-                valid[r] = True
-                used.append(int(sids[r]))
-                true_toks += n
-            # pad rows stage in DISTINCT spare slots (their content is
-            # gathered, run, and written back unchanged — valid is False)
-            spare = [s for s in range(self.scfg.max_batch)
-                     if s not in used]
-            for r in range(len(rows), self.Rp):
-                sids[r] = spare.pop()
+            tok, sids, pos0r, lens, valid, true_toks = block
             fg = (failed_group if self._model_ft(failed_group) is not None
                   else None)
-            self._pack_cache, self._pack_hlast = self._prefill_packed(
-                self.ft_params, self._pack_cache, self._pack_hlast,
-                jnp.asarray(tok), jnp.asarray(sids), jnp.asarray(pos0r),
-                jnp.asarray(lens), jnp.asarray(valid), failed_group=fg)
+            with self._span("serve.prefill"):
+                self._pack_cache, self._pack_hlast = self._prefill_packed(
+                    self.ft_params, self._pack_cache, self._pack_hlast,
+                    jnp.asarray(tok), jnp.asarray(sids), jnp.asarray(pos0r),
+                    jnp.asarray(lens), jnp.asarray(valid), failed_group=fg)
             self.prefill_calls += 1
             self.metrics["packed_calls"] += 1
             self.metrics["packed_tokens"] += true_toks
@@ -939,8 +957,37 @@ class ServeEngine:
                     if r is not None]
             if all(int(p["rowpos"][i]) >= int(p["lengths_np"][i])
                    for i in live):
-                self._land_packed(p, failed_group)
+                with self._span("serve.land"):
+                    self._land_packed(p, failed_group)
         return bool(rows)
+
+    def _pack_block(self, rows: list) -> tuple:
+        """The host side of one packed step: the [Rp, Cp] token block and
+        its per-row (slot, pos0, length, valid) metadata for ``rows``, and
+        the count of true prompt tokens packed."""
+        tok = np.zeros((self.Rp, self.Cp), np.int32)
+        sids = np.zeros(self.Rp, np.int32)
+        pos0r = np.zeros(self.Rp, np.int32)
+        lens = np.zeros(self.Rp, np.int32)
+        valid = np.zeros(self.Rp, bool)
+        used = []
+        true_toks = 0
+        for r, (p, i) in enumerate(rows):
+            off = int(p["rowpos"][i])
+            n = min(self.Cp, int(p["lengths_np"][i]) - off)
+            tok[r, :n] = p["tokens_np"][i, off : off + n]
+            sids[r] = p["reqs"][i][0]
+            pos0r[r] = off
+            lens[r] = p["lengths_np"][i]
+            valid[r] = True
+            used.append(int(sids[r]))
+            true_toks += n
+        # pad rows stage in DISTINCT spare slots (their content is
+        # gathered, run, and written back unchanged — valid is False)
+        spare = [s for s in range(self.scfg.max_batch) if s not in used]
+        for r in range(len(rows), self.Rp):
+            sids[r] = spare.pop()
+        return tok, sids, pos0r, lens, valid, true_toks
 
     def _land_packed(self, p: dict, failed_group: Optional[int]):
         """Gather a finished packed batch's staging rows into [Bp]-row
@@ -1056,6 +1103,10 @@ class ServeEngine:
         ``failed_group`` injects a fail-stop into that entangled group's
         head-GEMM compute for this step — decode and admission projections
         alike; the kernel rolls it forward, so outputs are unchanged."""
+        with self._span("serve.step"):
+            return self._step(failed_group)
+
+    def _step(self, failed_group: Optional[int]) -> int:
         if failed_group is not None:
             if self.scfg.ft_mode != "entangle":
                 raise ValueError("failed_group requires ft_mode='entangle'")
@@ -1068,15 +1119,16 @@ class ServeEngine:
         # shed lapsed deadlines BEFORE spending any prefill compute on
         # them — they would miss their SLA anyway, and the refunded chunk
         # budget goes to requests that can still make it
-        if any(r.deadline_ms is not None for r in self.queue):
-            kept, shed = self.sched.shed_expired(self.queue)
-            self.queue = kept
-            for req in shed:
-                req.status = "shed"
-                req.out = np.zeros(0, np.int32)
-                req.t_done = self._clock()
-                self._rings.pop(id(req), None)
-                self.metrics["shed"] += 1
+        with self._span("serve.shed"):
+            if any(r.deadline_ms is not None for r in self.queue):
+                kept, shed = self.sched.shed_expired(self.queue)
+                self.queue = kept
+                for req in shed:
+                    req.status = "shed"
+                    req.out = np.zeros(0, np.int32)
+                    req.t_done = self._clock()
+                    self._rings.pop(id(req), None)
+                    self.metrics["shed"] += 1
         # admission: plan (EDF over the wait queue; with refill, freed
         # slots re-enter the stream mid-flight) and advance up to the
         # chunk budget. Unchunked admission completes a batch per call, so
@@ -1087,15 +1139,17 @@ class ServeEngine:
             # mixed-bucket admissions co-pack into the same [Rp, Cp]
             # program, then run up to max_prefill_per_step packed steps
             for _ in range(self.scfg.max_prefill_per_step):
-                while self._plan_admission():
-                    pass
+                with self._span("serve.plan"):
+                    while self._plan_admission():
+                        pass
                 if not self._advance_packed(failed_group):
                     break
         else:
             budget = (self.scfg.max_prefill_per_step
                       if self.scfg.prefill_chunk else float("inf"))
             while budget > 0:
-                self._plan_admission()
+                with self._span("serve.plan"):
+                    self._plan_admission()
                 p = self.sched.pick_batch(self._inflight)
                 if p is None:
                     break
@@ -1103,7 +1157,8 @@ class ServeEngine:
                 budget -= 1
         # zero any freed rows no landing scatter absorbed: decode below
         # sees exactly the state boundary admission would have produced
-        self._flush_recycled()
+        with self._span("serve.flush"):
+            self._flush_recycled()
         active_idx = [i for i, s in enumerate(self.slots) if s is not None]
         if active_idx:
             B = self.scfg.max_batch
@@ -1111,26 +1166,29 @@ class ServeEngine:
             active[active_idx] = True
             head = (None if self.scfg.ft_mode != "entangle"
                     else (self.head_q, self.w_scale))
-            nxt, self.cache = self._decode(
-                self.ft_params, self.cache, jnp.asarray(self.last_tok),
-                jnp.asarray(self.pos), jnp.asarray(active), head,
-                failed_group=failed_group)
+            with self._span("serve.decode"):
+                nxt, self.cache = self._decode(
+                    self.ft_params, self.cache, jnp.asarray(self.last_tok),
+                    jnp.asarray(self.pos), jnp.asarray(active), head,
+                    failed_group=failed_group)
             self.decode_calls += 1
             self._census_bump("decode", (len(active_idx), B))
-            nxt = np.asarray(nxt)
-            now = self._clock()
-            for i in active_idx:
-                s = self.slots[i]
-                req = s["req"]
-                self.pos[i] += 1
-                tok = int(nxt[i])
-                s["toks"].append(tok)
-                self.last_tok[i] = nxt[i]
-                self._emit(req, tok, now)
-                if (len(s["toks"]) >= req.max_new
-                        or (req.eos_token is not None
-                            and tok == req.eos_token)):
-                    self._finish(i)
+            with self._span("serve.decode.sync"):
+                nxt = np.asarray(nxt)
+            with self._span("serve.emit"):
+                now = self._clock()
+                for i in active_idx:
+                    s = self.slots[i]
+                    req = s["req"]
+                    self.pos[i] += 1
+                    tok = int(nxt[i])
+                    s["toks"].append(tok)
+                    self.last_tok[i] = nxt[i]
+                    self._emit(req, tok, now)
+                    if (len(s["toks"]) >= req.max_new
+                            or (req.eos_token is not None
+                                and tok == req.eos_token)):
+                        self._finish(i)
         return sum(s is not None for s in self.slots)
 
     def idle(self) -> bool:

@@ -39,6 +39,7 @@ def _argv(*extra):
     (["--replicas", "2", "--kill-replica", "1"], "--kill-replica-at"),
     (["--replicas", "4", "--max-replicas", "2"], "--max-replicas"),
     (["--scale-up-depth", "0"], "--scale-up-depth"),
+    (["--trace-dir", "t", "--replicas", "2"], "single-engine"),
 ])
 def test_bad_args_fail_at_parse_time(monkeypatch, capsys, extra, msg):
     monkeypatch.setattr(sys, "argv", _argv(*extra))
@@ -129,3 +130,25 @@ def test_compile_cache_placement(monkeypatch, tmp_path, env_set):
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
         cc.reset_cache()
+
+
+def test_trace_dir_writes_a_trace_and_prints_the_spans(monkeypatch, capsys,
+                                                       tmp_path):
+    """--trace-dir: the wave is traced with the engine's spans on the
+    profiler's host plane, and the span summary is printed."""
+    from jax.profiler import ProfileData
+
+    # keep this worker's later compiles out of the persistent cache
+    monkeypatch.setattr(launch_serve, "enable_compile_cache", lambda: None)
+    monkeypatch.setattr(sys, "argv", _argv(
+        "--requests", "3", "--max-new", "3", "--trace-dir", str(tmp_path)))
+    launch_serve.main()
+    out = capsys.readouterr().out
+    assert "median admit wait" in out and "ms per step over" in out
+    assert "3/3 requests completed" in out
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    names = {ev.name for plane in ProfileData.from_file(str(path)).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert {"serve.step", "serve.decode", "serve.decode.sync",
+            "serve.emit"} <= names
