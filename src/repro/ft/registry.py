@@ -9,9 +9,11 @@ projections and the MoE per-expert GEMMs — resolves its
 the serving lifetime) while each call shape gets its own block-size
 decision:
 
-  * ``blocks`` policy ``None`` — shape-clamped power-of-two defaults
-    (:func:`default_blocks`): the per-group row count of a decode step is
-    tiny (max_batch / M), so the wrapper's MXU-aligned 128-row default
+  * ``blocks`` policy ``None`` — :func:`default_blocks`: on the compiled
+    TPU backend, weight tiles of 1-2 MiB of int8 whose ``bk`` divides K,
+    within the kernel's VMEM budget; elsewhere shape-clamped powers of
+    two. Either way the row block covers the per-group row count, which
+    at decode is tiny (max_batch / M), so the wrapper's 128-row default
     would pad it ~64x with zero rows every step;
   * ``blocks`` policy ``"auto"`` — the :mod:`repro.kernels.autotune`
     subsystem; the engine's ``warm_autotune`` pre-sweeps every registered
@@ -33,6 +35,7 @@ import dataclasses
 from typing import Optional
 
 from repro.core.plan import EntanglePlan
+from repro.kernels.entangled_matmul import VMEM_BUDGET_BYTES, vmem_bytes
 
 
 def group_rows(rows: int, M: int) -> int:
@@ -62,9 +65,70 @@ def row_block(Bg: int, backend: Optional[str] = None) -> int:
     return _pow2_cover(Bg, _FLOORS.get(backend, _FLOOR_DEFAULT)["bb"], 128)
 
 
+def _divisor_block(K: int, step: int, cap: int) -> int:
+    """Largest multiple of ``step`` up to ``cap`` that divides ``K``
+    (0 if none)."""
+    for b in range(min(cap, K) // step * step, 0, -step):
+        if K % b == 0:
+            return b
+    return 0
+
+
+# weight tiles of the compiled dense kernel: a grid step costs a fixed
+# ~0.24 us besides pushing its weight tile through the MXU once, so every
+# call takes a tile of about TILE_BYTES int8 bytes (one v5e: at 16, 64, 128
+# and 512 rows per group such tiles beat 256 x 256 ones by 1.4-2x). ``bk``
+# divides K (a ``bk`` that does not makes ``ops`` pad — copy — the whole
+# weight matrix on every call) and stops at 2048 unless a narrow N needs a
+# deeper tile to reach TILE_MIN; ``bn`` need not divide N (the last column
+# block may be partial), is balanced over the column blocks, and shrinks —
+# then ``bk`` — until the working set of the call's rows and streams fits
+# the kernel's VMEM budget. The grouped (per-expert) kernel still pushes
+# each tile once per stream product and runs under the default scoped
+# VMEM limit, so grouped sites keep the shape-clamped powers of two.
+TILE_BYTES = 2 << 20
+TILE_MIN = 1 << 20
+_BK_CAP = 2048
+
+
+def _tpu_blocks(Bg: int, K: int, N: int, M: int, l: int) -> dict:
+    lo = _FLOORS["pallas_tpu"]
+    bb = row_block(Bg, "pallas_tpu")
+    n_cols = -(-N // lo["bn"])  # N in lane tiles
+    deep = -(-TILE_MIN // (n_cols * lo["bn"]))  # bk for the min tile
+    bk = (_divisor_block(K, lo["bk"], max(_BK_CAP, deep))
+          or _pow2_cover(K, lo["bk"], 256))
+    if bk < deep:  # the smallest divisor of K deep enough, if any
+        bk = next((b for b in range(bk, K + 1, lo["bk"])
+                   if K % b == 0 and b >= deep), bk)
+    per = max(1, min(TILE_BYTES // bk // lo["bn"], n_cols))
+
+    def fits(per, bk):  # the worst call: int32 activations and container
+        return vmem_bytes(bb, per * lo["bn"], bk, n=4, M=M, l=l,
+                          packed=False) <= VMEM_BUDGET_BYTES
+
+    while not fits(per, bk):
+        if per > 1:
+            per -= 1
+        elif not (bk := _divisor_block(K, lo["bk"], bk - lo["bk"])):
+            raise ValueError(f"no blocks of ({Bg}, {K}, {N}) at M={M} fit "
+                             f"{VMEM_BUDGET_BYTES} B of VMEM")
+    per = -(-n_cols // -(-n_cols // per))  # balance the column blocks
+    return {"bb": bb, "bn": per * lo["bn"], "bk": bk}
+
+
 def default_blocks(Bg: int, K: int, N: int,
-                   backend: Optional[str] = None) -> dict:
-    """Shape-clamped block sizes for one (Bg, K, N) protected GEMM."""
+                   backend: Optional[str] = None, *,
+                   plan: Optional[EntanglePlan] = None,
+                   grouped: bool = False) -> dict:
+    """Shape-clamped block sizes for one (Bg, K, N) protected GEMM: the
+    compiled dense kernel's weight tiles (see :data:`TILE_BYTES`), fitted
+    to the VMEM working set of ``plan``'s streams (M=4, l=8 if none),
+    else — and for ``grouped`` (per-expert) sites — powers of two capped
+    at 256."""
+    if backend == "pallas_tpu" and not grouped:
+        return _tpu_blocks(Bg, K, N, plan.M if plan else 4,
+                           plan.l if plan else 8)
     lo = _FLOORS.get(backend, _FLOOR_DEFAULT)
     return {"bb": row_block(Bg, backend),
             "bn": _pow2_cover(N, lo["bn"], 256),
@@ -136,7 +200,9 @@ class PlanRegistry:
         if e is None:
             blocks = self.blocks_policy
             if blocks is None:
-                blocks = default_blocks(*shape[-3:], backend)
+                blocks = default_blocks(*shape[-3:], backend,
+                                        plan=self.plan,
+                                        grouped=groups is not None)
             e = ProtectionPlan(site=site, shape=shape, backend=backend,
                                plan=self.plan, blocks=blocks,
                                grouped=groups is not None,

@@ -99,11 +99,13 @@ def unpack_int8(p: jax.Array, axis: int = -2, n: Optional[int] = None
 # int8 limbs, x = sum_i limb_i << 8i, one limb per byte of its integer
 # type, and each limb gets its own int8 dot, recombined by shifts in the
 # int32 accumulator. Limbs are balanced ([-128, 127]): one holds an int8,
-# two an int16, and four hold ANY int32 exactly mod 2^32 (the top limb
-# wraps, and 2^24 * 256 == 2^32 vanishes) — the int32 accumulator's own
-# arithmetic, so a limb-split GEMM is bit-identical to the int32 GEMM it
-# replaces. The operand's dtype is its bound: callers that know their
-# values fit int8 (the eq.-13 activation grid) pass int8 and pay one limb.
+# two an int16 up to 32639 (the top 128 int16 values would need a top limb
+# of 128, which wraps to -128 and leaves them off by 2^16), and four hold
+# ANY int32 exactly mod 2^32 (the top limb wraps, and 2^24 * 256 == 2^32
+# vanishes) — the int32 accumulator's own arithmetic, so a limb-split GEMM
+# is bit-identical to the int32 GEMM it replaces. The operand's dtype is
+# its bound: callers that know their values fit int8 (the eq.-13
+# activation grid) pass int8 and pay one limb.
 # ---------------------------------------------------------------------------
 
 

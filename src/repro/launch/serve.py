@@ -375,6 +375,12 @@ def main():
     print(f"[launch.serve] {len(outs)}/{args.requests} requests completed in "
           f"{eng.decode_calls} batched decode calls; first output: {first}")
     print(f"[launch.serve] shape census: {eng.census}")
+    if args.ft_mode == "entangle":
+        m = eng.metrics
+        print(f"[launch.serve] weight tiles: {m['ft_decode_tile_sites']} of "
+              f"{m['ft_sites']} protected GEMMs ({m['ft_decode_sites']} at "
+              f"decode rows) take decode-sized tiles, the smallest "
+              f"{m['ft_decode_tile_min_bytes']} B")
 
     if args.smoke and args.ft_mode == "entangle":
         # per-scope recovery summary: drill the head scope AND the

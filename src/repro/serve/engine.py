@@ -154,7 +154,7 @@ from repro.dist import sharding
 from repro.ft import (SCOPES, FTContext, PlanRegistry, compile_plans,
                       prepare_params)
 from repro.ft.quantize import quantize_weight_stacked
-from repro.ft.registry import row_block
+from repro.ft.registry import TILE_MIN, default_blocks, group_rows
 from repro.ft.heads import ft_logits_decode, ft_logits_prefill
 from repro.kernels import ops as kops
 from repro.models.api import get_model
@@ -486,7 +486,16 @@ class ServeEngine:
             self.ft_params = warm["ft_params"]
             if self.plans is not None:
                 self.ftx = self.ftx.with_plans(self.plans)
-            return
+        else:
+            self._compile_plans(params)
+        if scfg.ft_mode == "entangle":
+            self.metrics.update(self._tile_counts())
+
+    def _compile_plans(self, params) -> None:
+        """Census every protected shape, freeze the plans, quantize the
+        protected sites' weights once and, with ``blocks="auto"``, sweep
+        the autotuner — a fresh (not warm-started) engine's startup."""
+        scfg = self.scfg
         with self._span("serve.init.census"):
             self.protected_census = self._protected_shape_census()
         # every chunk width any admission — boundary or refill — can run:
@@ -579,15 +588,32 @@ class ServeEngine:
         raise AssertionError("unreachable: submit() rejects oversize")
 
     def _default_head_blocks(self):
-        """Head-GEMM block sizes when the user gave none: the per-group
-        decode batch is tiny (max_batch / M rows), so the wrapper's
-        MXU-aligned bb=128 default would pad it ~64x with zero rows every
-        step — clamp bb to the smallest legal power of two covering the
-        group."""
+        """Head-GEMM block sizes when the user gave none: the protected
+        sites' shape policy (:func:`repro.ft.registry.default_blocks`) at
+        the decode step's rows per group. The admission head runs on at
+        most as many rows, so the same blocks suit it."""
         if self.scfg.blocks is not None or self.scfg.ft_mode != "entangle":
             return self.scfg.blocks
-        gsz = self.scfg.max_batch // self.scfg.ft_M
-        return {"bb": row_block(gsz, kops.resolve_backend())}
+        D, V = self._head_dims
+        return default_blocks(group_rows(self.scfg.max_batch, self.scfg.ft_M),
+                              D, V, kops.resolve_backend(), plan=self.plan)
+
+    def _tile_counts(self) -> dict:
+        """Protected GEMMs by weight tile, counted once at plan compile:
+        every (site, call shape) plan plus the decode head, those at the
+        decode step's rows, those of them given decode-sized weight tiles
+        (at least :data:`repro.ft.registry.TILE_MIN` int8 bytes), and the
+        smallest weight tile among them."""
+        Bg = group_rows(self.scfg.max_batch, self.scfg.ft_M)
+        calls = [(Bg, self._head_blocks)] + [
+            (p.shape[-3], p.blocks) for p in self.plans or ()]
+        tiles = [b["bn"] * b["bk"] for rows, b in calls
+                 if rows == Bg and isinstance(b, dict) and "bn" in b
+                 and "bk" in b]
+        return {"ft_sites": len(calls),
+                "ft_decode_sites": sum(rows == Bg for rows, _ in calls),
+                "ft_decode_tile_sites": sum(t >= TILE_MIN for t in tiles),
+                "ft_decode_tile_min_bytes": min(tiles, default=0)}
 
     # -- jitted programs ------------------------------------------------------
 

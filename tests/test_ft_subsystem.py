@@ -171,3 +171,117 @@ def test_pretuned_seed_cache_cold_hit(tmp_path, monkeypatch):
         assert eng3.plans.misses == 0
     finally:
         autotune.reset_cache(None)
+
+
+BENCH_CELLS = ["qwen2-7b.chat.ft-all", "falcon-mamba-7b.batch.ft-all"]
+
+
+def _bench_engine(cell_name: str):
+    """The serving engine of a benchmark cell at its full widths, built on
+    abstract weights (nothing is allocated) with the compiled TPU backend
+    pinned, so its plans are the ones the chip runs."""
+    from bench import harness
+    from repro.kernels import ops
+    from repro.serve.engine import ServeEngine
+
+    cell = harness.load_cell(cell_name)
+    cfg, scfg = harness.program_objects(cell)
+    shapes = jax.eval_shape(
+        lambda k: cell.arch.init_params(k, c=cell.config),
+        jax.random.PRNGKey(0))
+    box = {}
+
+    def build(params):
+        box["eng"] = ServeEngine(cfg, scfg, params)
+        return 0
+
+    ops.set_default_backend("pallas_tpu")
+    try:
+        jax.eval_shape(build, shapes)
+    finally:
+        ops.set_default_backend(None)
+    return box["eng"]
+
+
+@pytest.mark.parametrize("cell", BENCH_CELLS)
+def test_tpu_tile_policy_at_bench_sites(cell):
+    """Every protected GEMM of a benchmark configuration, at decode rows
+    and packed-prefill rows, gets compiled-backend blocks that tile int8
+    as (32, 128), a ``bk`` dividing K (no per-call weight pad), a working
+    set within the kernel's VMEM budget and, at decode rows, a weight tile
+    of at least 1 MiB. The head's blocks come from the same policy, and
+    the engine's tile counter puts every decode site in the decode-tile
+    class."""
+    from repro.ft.registry import TILE_MIN, group_rows
+    from repro.kernels.entangled_matmul import VMEM_BUDGET_BYTES, vmem_bytes
+
+    eng = _bench_engine(cell)
+    Bg = group_rows(eng.scfg.max_batch, eng.scfg.ft_M)
+    D, V = eng._head_dims
+    assert eng._head_blocks == default_blocks(Bg, D, V, "pallas_tpu",
+                                              plan=eng.plan)
+    calls = [(p.site, p.shape[-3], p.shape[-2], p.shape[-1], p.blocks)
+             for p in eng.plans] + [("head", Bg, D, V, eng._head_blocks)]
+    assert {rows for _, rows, _, _, _ in calls} == {
+        Bg, group_rows(eng.scfg.token_budget, eng.scfg.ft_M)}
+    for site, rows, K, N, b in calls:
+        assert K % b["bk"] == 0, (site, rows, b)
+        assert b["bb"] % 32 == b["bn"] % 128 == b["bk"] % 128 == 0, b
+        assert vmem_bytes(b["bb"], b["bn"], b["bk"], n=4,
+                          packed=False) <= VMEM_BUDGET_BYTES, (site, b)
+        if rows == Bg:
+            assert b["bn"] * b["bk"] >= TILE_MIN, (site, b)
+    m = eng.metrics
+    decode = sum(rows == Bg for _, rows, _, _, _ in calls)
+    assert m["ft_sites"] == len(calls)
+    assert m["ft_decode_sites"] == m["ft_decode_tile_sites"] == decode
+    assert m["ft_decode_tile_min_bytes"] >= TILE_MIN
+
+
+@pytest.mark.parametrize("M", [3, 4, 8])
+def test_tpu_tile_policy_fits_the_plan(M):
+    """The registry's compiled-backend blocks for dense sites fit the
+    kernel's VMEM budget at the plan's stream count and shift, at decode
+    and packed-prefill rows of the benchmark's widest sites, with ``bk``
+    dividing K; grouped (per-expert) sites keep the shape-clamped powers
+    of two the grouped kernel is built for."""
+    from repro.kernels.entangled_matmul import VMEM_BUDGET_BYTES, vmem_bytes
+
+    plan = make_plan(M, 32)
+    reg = PlanRegistry(plan)
+    for rows in (8, 2048):
+        for K, N in ((3584, 18944), (18944, 3584), (4096, 16384),
+                     (8192, 4096)):
+            b = reg.entry("mlp.up", rows=rows, K=K, N=N,
+                          backend="pallas_tpu").blocks
+            assert b == default_blocks(-(-rows // M), K, N, "pallas_tpu",
+                                       plan=plan)
+            assert K % b["bk"] == 0, (rows, K, N, b)
+            assert vmem_bytes(b["bb"], b["bn"], b["bk"], n=4, M=M, l=plan.l,
+                              packed=False) <= VMEM_BUDGET_BYTES, b
+    e = reg.entry("moe.up", rows=16, K=2048, N=1408, backend="pallas_tpu",
+                  groups=8)
+    assert e.grouped and e.blocks == {"bb": 32, "bn": 256, "bk": 256}
+
+
+def test_cpu_engine_counts_no_decode_tiles():
+    """Off the compiled backend the head takes the shape-clamped defaults,
+    and no decode site is counted with a decode-sized weight tile."""
+    from repro.configs import get_smoke_config
+    from repro.ft.registry import TILE_MIN, group_rows
+    from repro.kernels import ops
+    from repro.models import get_model
+    from repro.serve import ServeConfig, ServeEngine
+
+    cfg = get_smoke_config("llama3.2-1b")
+    params = get_model(cfg).init(jax.random.PRNGKey(0), cfg, max_seq=48)
+    eng = ServeEngine(cfg, ServeConfig(max_batch=8, max_seq=48,
+                                       ft_mode="entangle", ft_scope="all"),
+                      params)
+    D, V = eng._head_dims
+    assert eng._head_blocks == default_blocks(
+        group_rows(8, 4), D, V, ops.resolve_backend())
+    m = eng.metrics
+    assert m["ft_sites"] == len(eng.plans) + 1 and m["ft_decode_sites"] >= 1
+    assert m["ft_decode_tile_sites"] == 0
+    assert 0 < m["ft_decode_tile_min_bytes"] < TILE_MIN

@@ -8,7 +8,9 @@ lowering cannot express. Nothing runs, so they say nothing of results or
 times — the interpret-mode oracle tests cover results.
 
 Widths are ``llama3.2-1b``'s: q/o K=N=2048, k/v K=2048 N=512, gate/up
-K=2048 N=8192, down K=8192 N=2048, head K=2048 N=128256. The topology is
+K=2048 N=8192, down K=8192 N=2048, head K=2048 N=128256; and the
+benchmark's Qwen2-7B and Falcon-Mamba-7B sites at decode and packed-prefill
+rows with the blocks the serving policy gives them. The topology is
 described only inside the module fixture: the TPU library may be loaded
 by one process at a time, so it is never touched while modules import.
 """
@@ -73,10 +75,10 @@ def _compile(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile()
 
 
-def _blocks(K, N):
+def _blocks(K, N, grouped=False):
     """The protected sites' default blocks on the compiled backend (a
     decode step of 8 slots: 2 rows per stream)."""
-    return default_blocks(8 // M, K, N, "pallas_tpu")
+    return default_blocks(8 // M, K, N, "pallas_tpu", grouped=grouped)
 
 
 @pytest.mark.parametrize("site,K,N", SITES, ids=[s[0] for s in SITES])
@@ -92,6 +94,56 @@ def test_entangled_matmul_compiles(one_chip, no_persistent_cache,
     compiled = _compile(lambda c, g: entangled_matmul_pallas(
         c, g, plan=PLAN, fuse_epilogue=True, packed=packed, **bl), c, g)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# (config, site, rows per stream group, K, N) of the benchmark's protected
+# GEMMs: decode rows (max_batch / M) and packed-prefill rows (2048 / M);
+# the head runs on decode rows only
+BENCH_SITES = [
+    (cfg, site, rows, K, N)
+    for cfg, dec, sites in (
+        ("qwen2-7b", 8, [("q", 3584, 3584), ("k", 3584, 512),
+                         ("gate", 3584, 18944), ("down", 18944, 3584),
+                         ("head", 3584, 38016)]),
+        ("falcon", 16, [("in_proj", 4096, 16384), ("out_proj", 8192, 4096),
+                        ("head", 4096, 16256)]))
+    for site, K, N in sites
+    for rows in ((dec,) if site == "head" else (dec, 512))]
+
+
+@pytest.mark.parametrize(
+    "cfg,site,rows,K,N", BENCH_SITES,
+    ids=[f"{c}-{s}-{r}" for c, s, r, _, _ in BENCH_SITES])
+def test_bench_sites_compile_without_weight_copy(
+        one_chip, no_persistent_cache, cfg, site, rows, K, N):
+    """The fused kernel through ``ops`` at a benchmark site's width with
+    the policy's blocks (decode-sized weight tiles at decode rows): it
+    compiles for the chip, and no pad or copy as large as the packed
+    weights is made per call — ``bk`` divides K, and N is never padded."""
+    from repro.kernels import ops
+
+    c = jax.ShapeDtypeStruct((M, rows, K), jnp.int8, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((K // PACK_LANES, N), jnp.int32,
+                             sharding=one_chip)
+    bl = default_blocks(rows, K, N, "pallas_tpu")
+    text = _compile(lambda c, g: ops.entangled_matmul(
+        c, g, PLAN, fuse_epilogue=True, failed=1, packed=True, blocks=bl,
+        backend="pallas_tpu"), c, g).as_text()
+    assert "tpu_custom_call" in text
+    # the int8 activations' rows may pad; no int32 array the size of the
+    # packed weights is made
+    _assert_no_copy_over(text, K * N // PACK_LANES)
+
+
+def _assert_no_copy_over(text, words):
+    """No int32 pad or copy in the compiled ``text`` holds ``words``
+    elements or more."""
+    for dims, op in re.findall(r"= s32\[([0-9,]*)\]\S* (pad|copy)\(",
+                               text):
+        size = 1
+        for d in dims.split(","):
+            size *= int(d or 1)
+        assert size < words, (op, dims)
 
 
 def test_sharded_head_compiles_without_weight_copy(topo, no_persistent_cache):
@@ -147,7 +199,51 @@ def test_entangled_matmul_grouped_compiles(one_chip, no_persistent_cache,
                              jnp.int32, sharding=one_chip)
     _compile(lambda c, g: entangled_matmul_grouped_pallas(
         c, g, plan=PLAN, fuse_epilogue=True, failed=1, packed=packed,
-        **_blocks(K, N)), c, g)
+        **_blocks(K, N, grouped=True)), c, g)
+
+
+@pytest.mark.parametrize("packed", [True, False], ids=["packed", "int32"])
+def test_grouped_moe_width_compiles_without_weight_copy(
+        one_chip, no_persistent_cache, packed):
+    """The grouped kernel through ``ops`` at an MoE expert's width (K=2048,
+    N=1408: the last column block is partial) with the registry's grouped
+    blocks, 8 experts, 16 rows each per stream: it compiles for the chip
+    under the default scoped VMEM limit, and no pad or copy as large as
+    the expert weights is made per call."""
+    from repro.kernels import ops
+
+    E, K, N = 8, 2048, 1408
+    bl = default_blocks(16, K, N, "pallas_tpu", grouped=True)
+    c = jax.ShapeDtypeStruct((M, E, 16, K), jnp.int8, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((E, K // PACK_LANES if packed else K, N),
+                             jnp.int32, sharding=one_chip)
+    text = _compile(lambda c, g: ops.entangled_matmul_grouped(
+        c, g, PLAN, fuse_epilogue=True, failed=1, packed=packed, blocks=bl,
+        backend="pallas_tpu"), c, g).as_text()
+    assert "tpu_custom_call" in text
+    _assert_no_copy_over(text, E * K * N // (PACK_LANES if packed else 1))
+
+
+@pytest.mark.parametrize("rows,dtype", [(2, jnp.int8), (256, jnp.int32)],
+                         ids=["decode-int8", "prefill-int32"])
+def test_eight_streams_compile_without_weight_copy(
+        one_chip, no_persistent_cache, rows, dtype):
+    """The dense kernel through ``ops`` with M=8 streams (l=4: every
+    predecessor limb term is stacked) at the Qwen2-7B gate width, with the
+    blocks the policy fits to that plan; int32 activations at prefill rows
+    are the largest working set the policy reckons."""
+    from repro.kernels import ops
+
+    plan8 = make_plan(8)
+    K, N = 3584, 18944
+    bl = default_blocks(rows, K, N, "pallas_tpu", plan=plan8)
+    c = jax.ShapeDtypeStruct((8, rows, K), dtype, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((K, N), jnp.int32, sharding=one_chip)
+    text = _compile(lambda c, g: ops.entangled_matmul(
+        c, g, plan8, fuse_epilogue=True, failed=1, packed=False, blocks=bl,
+        backend="pallas_tpu"), c, g).as_text()
+    assert "tpu_custom_call" in text
+    _assert_no_copy_over(text, K * N)
 
 
 @pytest.mark.parametrize("packed", [True, False], ids=["packed", "int32"])
