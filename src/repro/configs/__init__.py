@@ -4,6 +4,7 @@ from repro.configs.base import (
     ModelConfig,
     MoEConfig,
     RGLRUConfig,
+    RopeScaling,
     SHAPE_CELLS,
     SSMConfig,
     ShapeCell,
@@ -19,6 +20,7 @@ __all__ = [
     "ModelConfig",
     "MoEConfig",
     "RGLRUConfig",
+    "RopeScaling",
     "SHAPE_CELLS",
     "SSMConfig",
     "ShapeCell",

@@ -20,9 +20,23 @@ class MoEConfig:
     gating: str = "softmax"  # softmax (v2) | sigmoid (v3)
     first_dense_layers: int = 0  # leading layers that use a dense FFN
     capacity_factor: float = 1.25  # expert buffer slack; >= n_experts/top_k
-    #   makes dispatch dropless (exactness tests use that)
+    #   makes dispatch dropless: every held expert's buffer then holds all
+    #   tokens of the call (a token picks an expert at most once)
     dispatch: str = "grouped"  # grouped (shard-local + EP all-to-all, §Perf
     #   iteration 1) | global_sort (pre-iteration baseline)
+    norm_topk_prob: bool = True  # renormalize the top-k routing weights to
+    #   sum to 1 (False: the raw softmax probabilities, DeepSeek-V2)
+    # this chip's share of the routed experts under expert parallelism:
+    # experts first_held .. first_held + n_held - 1 are held (0 = all);
+    # the router still scores all n_experts, and assignments to experts
+    # held elsewhere contribute nothing here
+    n_held: int = 0
+    first_held: int = 0
+
+    @property
+    def held(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.n_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,6 +48,23 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN context extension of the rotary embedding (DeepSeek-V2's
+    ``rope_scaling``): frequencies blend between interpolated and original
+    along a linear ramp between the correction dims of ``beta_fast`` and
+    ``beta_slow`` rotations; cos/sin scale by mscale(factor, mscale) /
+    mscale(factor, mscale_all_dim), and MLA's softmax scale by
+    mscale(factor, mscale_all_dim)^2."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -84,6 +115,7 @@ class ModelConfig:
     head_dim: int = 0  # 0 = d_model // n_heads
     qkv_bias: bool = False
     rope_theta: float = 1e4
+    rope_scaling: Optional[RopeScaling] = None  # None: plain rotary
     norm_eps: float = 1e-6
     norm_kind: str = "rmsnorm"  # rmsnorm | layernorm
     tie_embeddings: bool = False

@@ -2,13 +2,15 @@
 
 [arXiv:2405.04434; hf] 27L d_model=2048 16H d_ff_expert=1408 vocab=102400,
 MLA kv_lora=512 (no q-lora), 2 shared + 64 routed experts top-6, first layer
-dense (d_ff=10944), softmax gating.
+dense (d_ff=10944), softmax gating with the top-6 weights left
+un-normalized (``norm_topk_prob`` false), YaRN rope (factor 40 over 4096
+positions, mscale = mscale_all_dim = 0.707).
 
 Note: the assignment line reads "MoE 64e top-6 ... 2 shared+160 routed"; 160
 routed is full V2 — the V2-LITE checkpoint has 64 routed experts, which the
 "64e top-6" prefix (and HF config) confirms, so 64 is used.
 """
-from repro.configs.base import MLAConfig, ModelConfig, MoEConfig
+from repro.configs.base import MLAConfig, ModelConfig, MoEConfig, RopeScaling
 
 CONFIG = ModelConfig(
     name="deepseek-v2-lite-16b",
@@ -20,6 +22,14 @@ CONFIG = ModelConfig(
     d_ff=10944,  # dense first layer
     vocab_size=102400,
     rope_theta=1e4,
+    rope_scaling=RopeScaling(
+        factor=40.0,
+        original_max_position_embeddings=4096,
+        beta_fast=32.0,
+        beta_slow=1.0,
+        mscale=0.707,
+        mscale_all_dim=0.707,
+    ),
     moe=MoEConfig(
         n_experts=64,
         top_k=6,
@@ -27,6 +37,7 @@ CONFIG = ModelConfig(
         d_ff_expert=1408,
         gating="softmax",
         first_dense_layers=1,
+        norm_topk_prob=False,
     ),
     mla=MLAConfig(
         kv_lora_rank=512,

@@ -125,14 +125,20 @@ def default_blocks(Bg: int, K: int, N: int,
     compiled dense kernel's weight tiles (see :data:`TILE_BYTES`), fitted
     to the VMEM working set of ``plan``'s streams (M=4, l=8 if none),
     else — and for ``grouped`` (per-expert) sites — powers of two capped
-    at 256."""
+    at 256 (a compiled grouped ``bk`` divides K where a multiple of 128
+    up to 256 does)."""
     if backend == "pallas_tpu" and not grouped:
         return _tpu_blocks(Bg, K, N, plan.M if plan else 4,
                            plan.l if plan else 8)
     lo = _FLOORS.get(backend, _FLOOR_DEFAULT)
+    bk = _pow2_cover(K, lo["bk"], 256)
+    if backend == "pallas_tpu" and K % bk:
+        # a bk that does not divide K pads (copies) every expert's weights
+        # on each call: take the largest dividing one (DeepSeek-V2-Lite's
+        # expert down projection, K=1408, takes 128)
+        bk = _divisor_block(K, lo["bk"], 256) or bk
     return {"bb": row_block(Bg, backend),
-            "bn": _pow2_cover(N, lo["bn"], 256),
-            "bk": _pow2_cover(K, lo["bk"], 256)}
+            "bn": _pow2_cover(N, lo["bn"], 256), "bk": bk}
 
 
 @dataclasses.dataclass(frozen=True)

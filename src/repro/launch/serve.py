@@ -381,6 +381,11 @@ def main():
               f"{m['ft_sites']} protected GEMMs ({m['ft_decode_sites']} at "
               f"decode rows) take decode-sized tiles, the smallest "
               f"{m['ft_decode_tile_min_bytes']} B")
+    if cfg.moe is not None:
+        m = eng.metrics
+        print(f"[launch.serve] experts: {m['moe_experts_held']} of "
+              f"{cfg.moe.n_experts} held, {m['moe_grouped_sites']} grouped "
+              f"protected GEMMs")
 
     if args.smoke and args.ft_mode == "entangle":
         # per-scope recovery summary: drill the head scope AND the

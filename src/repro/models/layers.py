@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, RopeScaling
 from repro.dist.sharding import constrain
 
 ACT_DTYPE = jnp.bfloat16
@@ -60,15 +60,61 @@ def apply_norm(p, x, cfg: ModelConfig):
 
 # ------------------------------------------------------------------ rope ----
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """NeoX-style rotary embedding. x: [B, T, H, hd], positions: [B, T]."""
-    hd = x.shape[-1]
-    half = hd // 2
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor 0.1 * mscale * ln(factor) + 1
+    (1 for factor <= 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_correction_range(sc: RopeScaling, dim: int, theta: float):
+    """(low, high) rotary dims between which YaRN ramps from extrapolated
+    to interpolated frequencies: the dims that turn ``beta_fast`` and
+    ``beta_slow`` times over the original context, floored / ceiled and
+    clipped to [0, dim - 1]."""
+    def dim_of(rot):
+        return (dim * math.log(sc.original_max_position_embeddings
+                               / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = math.floor(dim_of(sc.beta_fast))
+    high = math.ceil(dim_of(sc.beta_slow))
+    return max(low, 0), min(high, dim - 1)
+
+
+def rope_freqs(half: int, theta: float,
+               scaling: Optional[RopeScaling] = None) -> jax.Array:
+    """Rotary inverse frequencies [half]; with YaRN ``scaling`` each is
+    blended between f / factor (interpolated) and f (extrapolated) by a
+    linear ramp over the correction range."""
     freqs = jnp.exp(
         -math.log(theta) * jnp.arange(0, half, dtype=jnp.float32) / half
     )
+    if scaling is None:
+        return freqs
+    low, high = _yarn_correction_range(scaling, 2 * half, theta)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return freqs / scaling.factor * ramp + freqs * (1.0 - ramp)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         scaling: Optional[RopeScaling] = None) -> jax.Array:
+    """NeoX-style rotary embedding. x: [B, T, H, hd], positions: [B, T].
+    ``scaling`` (YaRN) blends the frequencies (:func:`rope_freqs`) and
+    scales cos/sin by mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim)."""
+    hd = x.shape[-1]
+    half = hd // 2
+    freqs = rope_freqs(half, theta, scaling)
     ang = positions[..., None].astype(jnp.float32) * freqs  # [B, T, half]
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
@@ -362,8 +408,8 @@ def apply_attention(
                 positions = off[:, None] + jnp.arange(T)[None]
             else:
                 positions = jnp.broadcast_to(jnp.arange(T) + off, (B, T))
-            q = rope(q, positions, rope_theta)
-            k = rope(k, positions, rope_theta)
+            q = rope(q, positions, rope_theta, cfg.rope_scaling)
+            k = rope(k, positions, rope_theta, cfg.rope_scaling)
         q = constrain(q, "batch", "seq", "heads", None)
         k = constrain(k, "batch", "seq", "kv_heads", None)
         v = constrain(v, "batch", "seq", "kv_heads", None)
@@ -513,8 +559,10 @@ def apply_mla(p, x, *, cfg: ModelConfig, cache=None, pos=None, mode="train",
               lengths=None, ft=None):
     """Multi-head latent attention (DeepSeek). The cache stores ONLY the
     compressed latent c_kv [B, S, r] + shared k_rope — the paper-faithful
-    KV-compression; decode up-projects cached latents (the absorbed-weight
-    variant is a recorded §Perf hillclimb candidate).
+    KV-compression; decode up-projects cached latents, or with
+    ``cfg.mla_absorb`` scores the latents directly (absorbed W_uk / W_uv).
+    YaRN (``cfg.rope_scaling``) scales the rope dims' frequencies and the
+    softmax scale by mscale(factor, mscale_all_dim)^2.
 
     Chunked prefill: ``pos`` (static int) offsets rope positions and the
     latent-cache write; chunk queries attend over all cached latents so
@@ -555,8 +603,9 @@ def apply_mla(p, x, *, cfg: ModelConfig, cache=None, pos=None, mode="train",
         positions = off[:, None] + jnp.arange(T)[None]
     else:
         positions = jnp.broadcast_to(jnp.arange(T) + off, (B, T))
-    q_rope = rope(q_rope, positions, cfg.rope_theta)
-    k_rope_new = rope(k_rope_new[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    q_rope = rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
+    k_rope_new = rope(k_rope_new[:, :, None, :], positions, cfg.rope_theta,
+                      cfg.rope_scaling)[:, :, 0]
 
     new_cache = cache
     packed_qpos = None  # set on the token-packed path
@@ -598,29 +647,35 @@ def apply_mla(p, x, *, cfg: ModelConfig, cache=None, pos=None, mode="train",
     from repro.models.attention_core import (attend, attend_decode,
                                              attend_prefill_packed)
 
+    scale = 1.0 / math.sqrt(dn + dr)
+    rs = cfg.rope_scaling
+    if rs is not None and rs.mscale_all_dim:
+        scale *= yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+
     if mode == "decode" and cfg.mla_absorb:
         # absorbed projections: fold W_uk into q and W_uv out of the value
         # sum, so attention runs over the r-dim latents themselves and the
         # up-projection happens ONCE per step, not per cached position.
-        wb = p["wkv_b"]["w"].reshape(m.kv_lora_rank, H, dn + dv)
-        w_uk, w_uv = wb[..., :dn], wb[..., dn:]
-        scale = 1.0 / math.sqrt(dn + dr)
-        q_eff = jnp.einsum("bthd,rhd->bthr", q_nope.astype(jnp.float32),
-                           w_uk.astype(jnp.float32))
-        s_nope = jnp.einsum("bthr,bsr->bhts", q_eff,
-                            ckv_s.astype(jnp.float32))
-        s_rope = jnp.einsum("bthd,bsd->bhts", q_rope.astype(jnp.float32),
-                            kr_s.astype(jnp.float32))
-        s = (s_nope + s_rope) * scale
-        slot = jnp.arange(Tk)
-        if _is_pos_vector(pos):  # per-row positions: mask [B, S]
-            ok = slot[None, :] <= jnp.asarray(pos)[:, None]
-            s = jnp.where(ok[:, None, None, :], s, -1e30)
-        else:
-            s = jnp.where((slot <= pos)[None, None, None], s, -1e30)
-        probs = jax.nn.softmax(s, axis=-1)
-        ctx = jnp.einsum("bhts,bsr->bthr", probs, ckv_s.astype(jnp.float32))
-        o = jnp.einsum("bthr,rhd->bthd", ctx, w_uv.astype(jnp.float32))
+        with jax.named_scope("mla.decode"):
+            wb = p["wkv_b"]["w"].reshape(m.kv_lora_rank, H, dn + dv)
+            w_uk, w_uv = wb[..., :dn], wb[..., dn:]
+            q_eff = jnp.einsum("bthd,rhd->bthr", q_nope.astype(jnp.float32),
+                               w_uk.astype(jnp.float32))
+            s_nope = jnp.einsum("bthr,bsr->bhts", q_eff,
+                                ckv_s.astype(jnp.float32))
+            s_rope = jnp.einsum("bthd,bsd->bhts", q_rope.astype(jnp.float32),
+                                kr_s.astype(jnp.float32))
+            s = (s_nope + s_rope) * scale
+            slot = jnp.arange(Tk)
+            if _is_pos_vector(pos):  # per-row positions: mask [B, S]
+                ok = slot[None, :] <= jnp.asarray(pos)[:, None]
+                s = jnp.where(ok[:, None, None, :], s, -1e30)
+            else:
+                s = jnp.where((slot <= pos)[None, None, None], s, -1e30)
+            probs = jax.nn.softmax(s, axis=-1)
+            ctx = jnp.einsum("bhts,bsr->bthr", probs,
+                             ckv_s.astype(jnp.float32))
+            o = jnp.einsum("bthr,rhd->bthd", ctx, w_uv.astype(jnp.float32))
         out = dense(p["wo"], o.reshape(B, T, H * dv).astype(ACT_DTYPE),
                     ft=ft, site="out.o")
         return constrain(out, "batch", "seq", "embed"), new_cache
@@ -630,7 +685,6 @@ def apply_mla(p, x, *, cfg: ModelConfig, cache=None, pos=None, mode="train",
     k_nope, v = kvb[..., :dn], kvb[..., dn:]
     k_nope = constrain(k_nope, "batch", "seq", "heads", None)
 
-    scale = 1.0 / math.sqrt(dn + dr)
     k_full = jnp.concatenate(
         [k_nope, jnp.broadcast_to(kr_s[:, :, None, :], (B, Tk, H, dr))], axis=-1
     )
@@ -699,22 +753,29 @@ def apply_mlp(p, x, *, cfg: ModelConfig, ft=None):
 
 # ------------------------------------------------------------------- MoE ----
 
-def _moe_capacity(n_tokens: int, cfg: ModelConfig) -> int:
+def moe_capacity(n_tokens: int, cfg: ModelConfig) -> int:
+    """Rows of each held expert's buffer for a call of ``n_tokens`` tokens
+    (per dispatch group): the capacity-factor share, at most ``n_tokens``
+    — a token picks an expert at most once, so that ceiling (reached at
+    ``capacity_factor >= n_experts / top_k``) drops nothing at any skew."""
     mc = cfg.moe
     c = int(math.ceil(n_tokens * mc.top_k / mc.n_experts * mc.capacity_factor))
-    c = min(c, n_tokens * mc.top_k)  # dropless ceiling
+    c = min(c, n_tokens)  # dropless ceiling
     return max(8, -(-c // 8) * 8)  # round up to 8
 
 
 def init_moe(key, cfg: ModelConfig):
+    """Router over all ``n_experts``; expert weights only for the held
+    share (``MoEConfig.held`` of them)."""
     mc = cfg.moe
     ks = _split(key, 5)
+    Eh = mc.held
     p = {
         "norm": init_norm(cfg, cfg.d_model),
         "router": _he(ks[0], (cfg.d_model, mc.n_experts)),
-        "we_gate": _he(ks[1], (mc.n_experts, cfg.d_model, mc.d_ff_expert), cfg.d_model),
-        "we_up": _he(ks[2], (mc.n_experts, cfg.d_model, mc.d_ff_expert), cfg.d_model),
-        "we_down": _he(ks[3], (mc.n_experts, mc.d_ff_expert, cfg.d_model), mc.d_ff_expert),
+        "we_gate": _he(ks[1], (Eh, cfg.d_model, mc.d_ff_expert), cfg.d_model),
+        "we_up": _he(ks[2], (Eh, cfg.d_model, mc.d_ff_expert), cfg.d_model),
+        "we_down": _he(ks[3], (Eh, mc.d_ff_expert, cfg.d_model), mc.d_ff_expert),
     }
     if mc.n_shared:
         p["shared"] = init_mlp(ks[4], cfg, d_ff=mc.n_shared * mc.d_ff_expert)
@@ -729,21 +790,30 @@ def apply_moe(p, x, *, cfg: ModelConfig, valid=None, ft=None):
     the capacity-bounded [G, E, C, D] buffer resharding (data <-> expert
     owners) — GSPMD lowers it to the canonical EP all-to-all. §Perf iteration
     1: replaces a global argsort whose GSPMD lowering all-gathered the full
-    [N, D] activations (collective-bound, see EXPERIMENTS.md).
+    [N, D] activations.
 
     dispatch='global_sort' keeps the pre-iteration path for A/B.
 
-    ``valid`` [B, T] (bucketed batched prefill) routes pad tokens to a
-    virtual out-of-range expert so they can never STEAL capacity slots from
-    real prompt tokens; their own outputs are garbage and discarded by the
+    Expert share (``MoEConfig.n_held``): the router scores all
+    ``n_experts`` and picks the top-k among them; only assignments to the
+    held experts ``first_held ..`` are dispatched and computed here, the
+    rest go to a virtual out-of-range expert and contribute nothing. The
+    shared experts are computed in full. A ``capacity_factor`` of at least
+    ``n_experts / top_k`` sizes every held expert's buffer to the tokens
+    of the call, so no assignment is ever dropped whatever the skew.
+
+    ``valid`` [B, T] (bucketed batched prefill) routes pad tokens to the
+    same virtual expert so they can never STEAL capacity slots from real
+    prompt tokens; their own outputs are garbage and discarded by the
     caller. (Capacity-factor dropping itself still depends on the batch
-    composition, so MoE batched serving is exact only modulo drops — the
-    same caveat as any capacity-bounded MoE engine.)"""
+    composition, so MoE batched serving is exact only modulo drops unless
+    dropless — the same caveat as any capacity-bounded MoE engine.)"""
     from repro.dist.sharding import axis_extent
 
     mc = cfg.moe
     B, T, D = x.shape
-    N, E, K = B * T, mc.n_experts, mc.top_k
+    N, K = B * T, mc.top_k
+    E = mc.held  # dispatch buffers cover the held experts; E = virtual
     h = apply_norm(p["norm"], x, cfg)
     hf = h.reshape(N, D)
 
@@ -753,56 +823,66 @@ def apply_moe(p, x, *, cfg: ModelConfig, valid=None, ft=None):
     n_loc = N // G
     hg = constrain(hf.reshape(G, n_loc, D), "batch", None, None)
 
-    # router in bf16 with f32 accumulation: avoids materializing an f32
-    # copy of the full [N, D] activations (§Perf iteration 4)
-    if ft is not None and ft.protects("mlp.router"):
-        # MoE routing decisions steer EVERY expert GEMM downstream —
-        # protecting this small projection makes routing itself fail-stop
-        # recoverable, so a failed group cannot silently reroute tokens
-        rw = ((p["router_q8"]["w"], p["router_q8"]["scale"])
-              if "router_q8" in p else p["router"])
-        logits = ft.matmul("mlp.router", hg, rw)
-    else:
-        logits = jnp.einsum("gnd,de->gne", hg,
-                            p["router"].astype(ACT_DTYPE),
-                            preferred_element_type=jnp.float32)
-    if mc.gating == "sigmoid":
-        probs = jax.nn.sigmoid(logits)
-    else:
-        probs = jax.nn.softmax(logits, axis=-1)
-    vals, idx = lax.top_k(probs, K)  # [G, n_loc, K]
-    weights = vals / (jnp.sum(vals, -1, keepdims=True) + 1e-9)
+    with jax.named_scope("moe.route"):
+        # router in bf16 with f32 accumulation: avoids materializing an
+        # f32 copy of the full [N, D] activations (§Perf iteration 4)
+        if ft is not None and ft.protects("mlp.router"):
+            # MoE routing decisions steer EVERY expert GEMM downstream —
+            # protecting this small projection makes routing itself
+            # fail-stop recoverable, so a failed group cannot silently
+            # reroute tokens
+            rw = ((p["router_q8"]["w"], p["router_q8"]["scale"])
+                  if "router_q8" in p else p["router"])
+            logits = ft.matmul("mlp.router", hg, rw)
+        else:
+            logits = jnp.einsum("gnd,de->gne", hg,
+                                p["router"].astype(ACT_DTYPE),
+                                preferred_element_type=jnp.float32)
+        if mc.gating == "sigmoid":
+            probs = jax.nn.sigmoid(logits)
+        else:
+            probs = jax.nn.softmax(logits, axis=-1)
+        vals, idx = lax.top_k(probs, K)  # [G, n_loc, K]
+        if mc.norm_topk_prob:
+            weights = vals / (jnp.sum(vals, -1, keepdims=True) + 1e-9)
+        else:
+            weights = vals
+        if mc.n_held:  # experts held elsewhere -> virtual expert
+            idx = idx - mc.first_held
+            idx = jnp.where((idx >= 0) & (idx < E), idx, E)
+        if valid is not None:
+            vg = valid.reshape(G, n_loc)
+            idx = jnp.where(vg[..., None], idx, E)  # pad tokens -> virtual
 
-    C = _moe_capacity(n_loc, cfg)
-    A = n_loc * K  # assignments per group
-    if valid is not None:
-        vg = valid.reshape(G, n_loc)
-        idx = jnp.where(vg[..., None], idx, E)  # pad tokens -> virtual expert
-    e_flat = idx.reshape(G, A)
-    w_flat = weights.reshape(G, A)
-    order = jnp.argsort(e_flat, axis=-1)  # stable: within-expert order = token order
-    e_s = jnp.take_along_axis(e_flat, order, axis=-1)
-    starts = jax.vmap(
-        lambda es: jnp.searchsorted(es, jnp.arange(E + 1), side="left"))(e_s)
+    with jax.named_scope("moe.dispatch"):
+        C = moe_capacity(n_loc, cfg)
+        A = n_loc * K  # assignments per group
+        e_flat = idx.reshape(G, A)
+        w_flat = weights.reshape(G, A)
+        order = jnp.argsort(e_flat, axis=-1)  # stable: within-expert order = token order
+        e_s = jnp.take_along_axis(e_flat, order, axis=-1)
+        starts = jax.vmap(
+            lambda es: jnp.searchsorted(es, jnp.arange(E + 1), side="left"))(e_s)
 
-    # GATHER-based capacity dispatch (§Perf iteration 3): buffer slot
-    # p = e*C + r pulls sorted-assignment starts[e]+r — no forward scatter
-    # (XLA's scatter expander materializes target-shaped index grids).
-    eidx = jnp.arange(E * C) // C
-    ridx = jnp.arange(E * C) % C
-    src = jnp.take_along_axis(starts, eidx[None].repeat(G, 0), axis=1) + ridx
-    # slot occupancy mask — deliberately NOT named `valid`: that's the
-    # [B, T] token-validity parameter, still live below
-    slot_ok = src < jnp.take_along_axis(starts, eidx[None].repeat(G, 0) + 1,
-                                        axis=1)
-    src = jnp.minimum(src, A - 1)
-    src_assign = jnp.take_along_axis(order, src, axis=1)  # [G, E*C] assignment id
-    src_tok = src_assign // K
-    rows = jnp.take_along_axis(hg, src_tok[..., None], axis=1)  # [G, E*C, D]
-    rows = constrain(rows, "batch", None, None)
-    expert_in = jnp.where(slot_ok[..., None], rows, 0).reshape(G, E, C, D)
-    # the EP boundary: data-sharded groups -> expert-sharded buffers
-    expert_in = constrain(expert_in, "batch", "experts", None, None)
+        # GATHER-based capacity dispatch (§Perf iteration 3): buffer slot
+        # p = e*C + r pulls sorted-assignment starts[e]+r — no forward
+        # scatter (XLA's scatter expander materializes target-shaped
+        # index grids).
+        eidx = jnp.arange(E * C) // C
+        ridx = jnp.arange(E * C) % C
+        src = jnp.take_along_axis(starts, eidx[None].repeat(G, 0), axis=1) + ridx
+        # slot occupancy mask — deliberately NOT named `valid`: that's the
+        # [B, T] token-validity parameter, still live below
+        slot_ok = src < jnp.take_along_axis(starts, eidx[None].repeat(G, 0) + 1,
+                                            axis=1)
+        src = jnp.minimum(src, A - 1)
+        src_assign = jnp.take_along_axis(order, src, axis=1)  # [G, E*C] assignment id
+        src_tok = src_assign // K
+        rows = jnp.take_along_axis(hg, src_tok[..., None], axis=1)  # [G, E*C, D]
+        rows = constrain(rows, "batch", None, None)
+        expert_in = jnp.where(slot_ok[..., None], rows, 0).reshape(G, E, C, D)
+        # the EP boundary: data-sharded groups -> expert-sharded buffers
+        expert_in = constrain(expert_in, "batch", "experts", None, None)
     if ft is not None and ft.protects("moe.gate"):
         # the per-expert batched GEMMs — the last big unprotected FLOPs of
         # the MoE block — run through the GROUPED entangled kernel: one
@@ -828,23 +908,25 @@ def apply_moe(p, x, *, cfg: ModelConfig, valid=None, ft=None):
                        p["we_up"].astype(ACT_DTYPE))
         out_e = jnp.einsum("gecf,efd->gecd", a,
                            p["we_down"].astype(ACT_DTYPE))
-    out_e = constrain(out_e, "batch", "experts", None, None)
-    h_flat = constrain(out_e.reshape(G, E * C, D), "batch", None, None)
 
-    # combine, also gather-based: assignment (t, k) sits at sorted position
-    # inv_order, rank within its expert = pos - starts[e], slot = e*C + rank
-    inv_order = jnp.argsort(order, axis=-1)  # [G, A]
-    rank = inv_order - jnp.take_along_axis(starts, e_flat, axis=1)
-    keep = rank < C
-    if valid is not None:
-        keep &= e_flat < E  # virtual-expert (pad) assignments contribute 0
-    slot = jnp.minimum(e_flat * C + rank, E * C - 1)
-    hsel = jnp.take_along_axis(h_flat, slot[..., None], axis=1)  # [G, A, D]
-    hsel = constrain(hsel, "batch", None, None)
-    contrib = jnp.where(keep[..., None],
-                        w_flat[..., None].astype(ACT_DTYPE) * hsel, 0)
-    out = contrib.reshape(G, n_loc, K, D).sum(axis=2)
-    out = constrain(out, "batch", None, None).reshape(N, D)
+    with jax.named_scope("moe.combine"):
+        out_e = constrain(out_e, "batch", "experts", None, None)
+        h_flat = constrain(out_e.reshape(G, E * C, D), "batch", None, None)
+        # combine, also gather-based: assignment (t, k) sits at sorted
+        # position inv_order, rank within its expert = pos - starts[e],
+        # slot = e*C + rank
+        inv_order = jnp.argsort(order, axis=-1)  # [G, A]
+        rank = inv_order - jnp.take_along_axis(starts, e_flat, axis=1)
+        keep = rank < C
+        if valid is not None or mc.n_held:
+            keep &= e_flat < E  # virtual-expert assignments contribute 0
+        slot = jnp.minimum(e_flat * C + rank, E * C - 1)
+        hsel = jnp.take_along_axis(h_flat, slot[..., None], axis=1)  # [G, A, D]
+        hsel = constrain(hsel, "batch", None, None)
+        contrib = jnp.where(keep[..., None],
+                            w_flat[..., None].astype(ACT_DTYPE) * hsel, 0)
+        out = contrib.reshape(G, n_loc, K, D).sum(axis=2)
+        out = constrain(out, "batch", None, None).reshape(N, D)
 
     if mc.n_shared:
         sp = dict(p["shared"])

@@ -490,6 +490,8 @@ class ServeEngine:
             self._compile_plans(params)
         if scfg.ft_mode == "entangle":
             self.metrics.update(self._tile_counts())
+        if cfg.moe is not None:
+            self.metrics.update(self._moe_counts())
 
     def _compile_plans(self, params) -> None:
         """Census every protected shape, freeze the plans, quantize the
@@ -615,6 +617,12 @@ class ServeEngine:
                 "ft_decode_tile_sites": sum(t >= TILE_MIN for t in tiles),
                 "ft_decode_tile_min_bytes": min(tiles, default=0)}
 
+    def _moe_counts(self) -> dict:
+        """The expert layer's shape, counted once at plan compile: routed
+        experts held here and grouped (per-expert) protected GEMM plans."""
+        return {"moe_experts_held": self.cfg.moe.held,
+                "moe_grouped_sites": sum(p.grouped for p in self.plans or ())}
+
     # -- jitted programs ------------------------------------------------------
 
     def _pad_sids(self, taken: list) -> tuple:
@@ -632,19 +640,21 @@ class ServeEngine:
     def _scatter_rows_impl(self, cache, pcache, sids, valid, zero):
         """Scatter ALL rows of an admission-batch (or zeros-template)
         pytree into the batched cache in ONE call: row j lands in slot
-        ``sids[j]``; rows with ``valid[j] == False`` write the slot's own
-        gathered content back (a no-op), and rows with ``zero[j] == True``
-        write ZEROS instead of their pcache content — recycled-slot
-        zeroing rides in the SAME scatter as the admission insert, so one
-        trace (and one dispatch) serves any mix of admission rows, recycle
-        rows and padding. ``sids``/``valid``/``zero`` are traced; the
-        caller guarantees sids are DISTINCT slots."""
+        ``sids[j]``; rows with ``valid[j] == False`` are sent past the
+        slot axis and dropped (the slot keeps its content), and rows with
+        ``zero[j] == True`` write ZEROS instead of their pcache content —
+        recycled-slot zeroing rides in the SAME scatter as the admission
+        insert, so one trace (and one dispatch) serves any mix of
+        admission rows, recycle rows and padding. ``sids``/``valid``/
+        ``zero`` are traced; the caller guarantees sids are DISTINCT
+        slots. Dropping (rather than gathering the slots' own rows to
+        write back) keeps the temporaries to about one copy of the
+        batch's rows."""
         def ins(big, small):
-            cur = jnp.take(big, sids, axis=1)
-            v = valid.reshape((1, -1) + (1,) * (big.ndim - 2))
             z = zero.reshape((1, -1) + (1,) * (big.ndim - 2))
             src = jnp.where(z, jnp.zeros_like(small), small)
-            return big.at[:, sids].set(jnp.where(v, src, cur))
+            idx = jnp.where(valid, sids, big.shape[1])
+            return big.at[:, idx].set(src, mode="drop")
         return jax.tree.map(ins, cache, pcache)
 
     def _scatter(self, pcache, sids, valid, zero):

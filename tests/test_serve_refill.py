@@ -141,6 +141,27 @@ def test_recycle_zeroing_rides_landing_scatter():
     assert len(done) == 3
 
 
+def test_landing_scatter_rows():
+    """The batched landing scatter, row by row: a valid row lands in its
+    slot, a zero row writes zeros there, an invalid row leaves its slot
+    as it was, and slots no row names are untouched."""
+    big = {"a": np.arange(2 * 6 * 3, dtype=np.float32).reshape(2, 6, 3),
+           "b": np.arange(2 * 6, dtype=np.int32).reshape(2, 6) + 100}
+    small = {"a": -np.ones((2, 4, 3), np.float32),
+             "b": -np.ones((2, 4), np.int32)}
+    sids = np.array([4, 1, 0, 5], np.int32)
+    valid = np.array([True, True, False, True])
+    zero = np.array([False, True, False, False])
+    out = ServeEngine._scatter_rows_impl(
+        None, jax.tree.map(jax.numpy.asarray, big), small, sids, valid, zero)
+    for k in big:
+        want = big[k].copy()
+        want[:, 4] = -1
+        want[:, 1] = 0
+        want[:, 5] = -1
+        np.testing.assert_array_equal(np.asarray(out[k]), want)
+
+
 def test_handle_iterator_streams_and_drives_engine():
     """submit() returns a handle; iterating it drives engine.step() on
     demand and yields exactly the tokens the request finished with."""

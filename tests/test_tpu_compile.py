@@ -266,3 +266,68 @@ def test_conv1d_compiles(one_chip, no_persistent_cache):
     x = jax.ShapeDtypeStruct((1, 256, 1024), jnp.int32, sharding=one_chip)
     w = jax.ShapeDtypeStruct((256, 4), jnp.int32, sharding=one_chip)
     _compile(lambda x, w: conv1d_causal_pallas(x, w, bd=128, bt=512), x, w)
+
+
+# DeepSeek-V2-Lite's routed-expert GEMMs at the benchmark cell's capacity
+# rows (dropless: every held expert's buffer holds the call's tokens, 64 a
+# decode step and 2048 a packed prefill, i.e. 16 and 512 a stream)
+EXPERT_SITES = [(site, rows, K, N)
+                for site, K, N in (("gate", 2048, 1408), ("down", 1408, 2048))
+                for rows in (64, 2048)]
+
+
+@pytest.mark.parametrize("site,rows,K,N", EXPERT_SITES,
+                         ids=[f"{s}-{r}" for s, r, _, _ in EXPERT_SITES])
+def test_expert_sites_compile_without_weight_copy(
+        one_chip, no_persistent_cache, site, rows, K, N):
+    """The grouped kernel through ``ops`` for 8 held experts at the
+    published expert width with the registry's grouped blocks: it compiles
+    for the chip, and ``bk`` divides K (1408 takes 128), so no pad or copy
+    of the packed expert weights is made per call."""
+    from repro.kernels import ops
+
+    E, Bg = 8, rows // M
+    bl = default_blocks(Bg, K, N, "pallas_tpu", grouped=True)
+    assert K % bl["bk"] == 0
+    c = jax.ShapeDtypeStruct((M, E, Bg, K), jnp.int8, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((E, K // PACK_LANES, N), jnp.int32,
+                             sharding=one_chip)
+    text = _compile(lambda c, g: ops.entangled_matmul_grouped(
+        c, g, PLAN, fuse_epilogue=True, failed=1, packed=True, blocks=bl,
+        backend="pallas_tpu"), c, g).as_text()
+    assert "tpu_custom_call" in text
+    _assert_no_copy_over(text, E * K * N // PACK_LANES)
+
+
+def test_absorbed_mla_decode_compiles(one_chip, no_persistent_cache):
+    """DeepSeek-V2-Lite's latent-attention decode at the benchmark cell's
+    64 slots x 5,120 positions, absorbed, with per-row positions and the
+    latent cache donated: it compiles for the chip, the cache is updated
+    in place (aliased), and the program's temporaries fit beside it."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import layers as L
+
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite-16b"),
+                              mla_absorb=True)
+    B, S = 64, 5120
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    p = jax.tree.map(sds, jax.eval_shape(
+        lambda k: L.init_mla(k, cfg, S), jax.random.PRNGKey(0)))
+    cache = jax.tree.map(sds, jax.eval_shape(
+        lambda: L.init_mla_cache(cfg, B, S)))
+    x = jax.ShapeDtypeStruct((B, 1, cfg.d_model), L.ACT_DTYPE,
+                             sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda p, x, c, pos: L.apply_mla(p, x, cfg=cfg, cache=c, pos=pos,
+                                         mode="decode"),
+        donate_argnums=(2,)).lower(p, x, cache, pos).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = B * S * (512 + 64) * 2
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < 4 * cache_bytes
